@@ -22,6 +22,9 @@ def main() -> None:
                                        "faults", "sweep", "scale"])
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (bench_exec_time, bench_faults, bench_kernels,
                    bench_mapping_algos, bench_nocsim, bench_overall,
                    bench_partition, bench_scale, bench_sweep)

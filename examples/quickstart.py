@@ -10,6 +10,7 @@ import sys
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import run_toolchain
 from repro.snn import PAPER_SNNS, make_snn, profile_snn
 
@@ -20,6 +21,7 @@ def main() -> None:
     ap.add_argument("--steps", type=int, default=400)
     ap.add_argument("--mesh", type=int, default=5, help="mesh side (5 => 5x5)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     print(f"[1/4] profiling {args.snn} ({args.steps} steps of LIF simulation)")
     topo = make_snn(args.snn)

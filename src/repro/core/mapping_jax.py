@@ -388,7 +388,6 @@ def island_sa(
     """Island-model SA under shard_map: independent populations per device,
     periodic all-gather of the global best to reseed each island's worst
     chain (the distributed-search story for large meshes)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     start = time.perf_counter()
@@ -431,15 +430,16 @@ def island_sa(
         costs_l = jax.vmap(lambda p: _cost(sym, p, dist))(placements_l)
         return placements_l, costs_l
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         island, mesh=mesh,
         in_specs=(P(axis), P(axis)),
         out_specs=(P(axis), P(axis)),
-        check_rep=False,
+        check_vma=False,
     )
-    placements, costs = sharded(placements, keys)
-    best_i = int(jnp.argmin(costs))
-    best = placements[best_i]
+    # Gather to the host before indexing: on a mesh with Explicit axes a
+    # device-side gather from a sharded array is a sharding error.
+    placements, costs = (np.asarray(a) for a in sharded(placements, keys))
+    best = jnp.asarray(placements[int(np.argmin(costs))])
     final_cost = float(_cost(sym, best, dist))
     seconds = time.perf_counter() - start
     return MappingResult(

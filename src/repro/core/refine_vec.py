@@ -466,12 +466,9 @@ def refine_level_vec(
         dense_ok = (n <= _KERNEL_MAX_N if objective == "cut"
                     else n <= _KERNEL_MAX_N and hyper.num_hyperedges <= _KERNEL_MAX_N)
         if dense_ok and k >= _KERNEL_MIN_K and total_w < (1 << 24):
-            try:
-                import jax
+            import jax
 
-                use_kernel = jax.default_backend() == "tpu"
-            except Exception:
-                use_kernel = False
+            use_kernel = jax.default_backend() == "tpu"
 
     if use_kernel:
         dense = (_dense_adjacency(graph) if objective == "cut"

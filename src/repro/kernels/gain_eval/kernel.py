@@ -29,6 +29,9 @@ __all__ = ["part_degrees_pallas", "connectivity_matmul_pallas"]
 BM = 128
 BN = 128
 BK = 128
+# Degrees are integer spike counts and the refiner needs them exact; the
+# MXU's default f32 matmul rounds its operands to bf16 (8 significant bits).
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _degrees_kernel(adj_ref, part_ref, out_ref, *, nk: int):
@@ -40,9 +43,10 @@ def _degrees_kernel(adj_ref, part_ref, out_ref, *, nk: int):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     pk = part_ref[...]  # (BK, 1) f32 partition ids (padding rows hold -1)
-    cols = jax.lax.broadcasted_iota(jnp.float32, (BK, BN), 1) + j * BN
+    cols = (jax.lax.broadcasted_iota(jnp.int32, (BK, BN), 1) + j * BN).astype(jnp.float32)
     onehot = (pk == cols).astype(jnp.float32)  # (BK, BN) tile, built in VMEM
-    out_ref[...] += jnp.dot(adj_ref[...], onehot, preferred_element_type=jnp.float32)
+    out_ref[...] += jnp.dot(adj_ref[...], onehot, preferred_element_type=jnp.float32,
+                            precision=_EXACT)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
@@ -93,7 +97,7 @@ def _matmul_kernel(a_ref, b_ref, out_ref):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     out_ref[...] += jnp.dot(a_ref[...], b_ref[...],
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32, precision=_EXACT)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

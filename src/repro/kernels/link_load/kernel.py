@@ -25,6 +25,9 @@ __all__ = ["link_loads_pallas"]
 BM = 128
 LANES = 128
 SUB = 8
+# Loads are integer packet counts compared against a link capacity; the
+# MXU's default f32 matmul rounds its operands to bf16.
+_EXACT = lax.Precision.HIGHEST
 
 
 def _pad_to(n: int, m: int) -> int:
@@ -55,44 +58,44 @@ def _kernel(c_ref, xa_ref, ya_ref, xb_ref, yb_ref,
     hq = s_ref.shape[1]  # padded H-1 lanes
 
     f32 = jnp.float32
-    wlink = lax.broadcasted_iota(f32, (1, wp), 1)  # link index w
-    qlink = lax.broadcasted_iota(f32, (1, hq), 1)  # link index q
+    wlink = lax.broadcasted_iota(jnp.int32, (1, wp), 1).astype(f32)  # link index w
+    qlink = lax.broadcasted_iota(jnp.int32, (1, hq), 1).astype(f32)  # link index q
     wvalid = wlink < (mesh_w - 1)
     qvalid = qlink < (mesh_h - 1)
 
     # ---- horizontal (row of a) ----
     u_e = jnp.where((xb.T > wlink) & wvalid, 1.0, 0.0)  # (K, Wp)
     u_w = jnp.where((xb.T <= wlink) & wvalid, 1.0, 0.0)
-    t_e = jnp.dot(c, u_e, preferred_element_type=f32)  # (BM, Wp)
-    t_w = jnp.dot(c, u_w, preferred_element_type=f32)
+    t_e = jnp.dot(c, u_e, preferred_element_type=f32, precision=_EXACT)  # (BM, Wp)
+    t_w = jnp.dot(c, u_w, preferred_element_type=f32, precision=_EXACT)
     m_ge = jnp.where(wlink >= xa, 1.0, 0.0)  # (BM, Wp) bcast
     m_lt = jnp.where(wlink < xa, 1.0, 0.0)
-    hrow = lax.broadcasted_iota(f32, (BM, hp), 1)
+    hrow = lax.broadcasted_iota(jnp.int32, (BM, hp), 1).astype(f32)
     y_onehot = jnp.where(hrow == ya, 1.0, 0.0)  # (BM, Hp)
     e_ref[...] += lax.dot_general(y_onehot, t_e * m_ge,
                                   (((0,), (0,)), ((), ())),
-                                  preferred_element_type=f32)
+                                  preferred_element_type=f32, precision=_EXACT)
     w_ref[...] += lax.dot_general(y_onehot, t_w * m_lt,
                                   (((0,), (0,)), ((), ())),
-                                  preferred_element_type=f32)
+                                  preferred_element_type=f32, precision=_EXACT)
 
     # ---- vertical (column of b) ----
     v_s = jnp.where((qlink >= ya) & qvalid, 1.0, 0.0)  # (BM, Hq): [y_a <= q]
     v_n = jnp.where((qlink < ya) & qvalid, 1.0, 0.0)  # (BM, Hq): [q < y_a]
     p_s = lax.dot_general(c, v_s, (((0,), (0,)), ((), ())),
-                          preferred_element_type=f32)  # (K, Hq)
+                          preferred_element_type=f32, precision=_EXACT)  # (K, Hq)
     p_n = lax.dot_general(c, v_n, (((0,), (0,)), ((), ())),
-                          preferred_element_type=f32)
+                          preferred_element_type=f32, precision=_EXACT)
     m_s = jnp.where(qlink < yb.T, 1.0, 0.0)  # (K, Hq): [q < y_b]
     m_n = jnp.where(qlink >= yb.T, 1.0, 0.0)  # (K, Hq): [y_b <= q]
-    wcol = lax.broadcasted_iota(f32, (k, wp2), 1)
+    wcol = lax.broadcasted_iota(jnp.int32, (k, wp2), 1).astype(f32)
     x_onehot = jnp.where(wcol == xb.T, 1.0, 0.0)  # (K, Wp2)
     s_ref[...] += lax.dot_general(x_onehot, p_s * m_s,
                                   (((0,), (0,)), ((), ())),
-                                  preferred_element_type=f32)
+                                  preferred_element_type=f32, precision=_EXACT)
     n_ref[...] += lax.dot_general(x_onehot, p_n * m_n,
                                   (((0,), (0,)), ((), ())),
-                                  preferred_element_type=f32)
+                                  preferred_element_type=f32, precision=_EXACT)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh_w", "mesh_h", "interpret"))

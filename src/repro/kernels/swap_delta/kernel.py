@@ -24,6 +24,9 @@ __all__ = ["swap_deltas_pallas"]
 BM = 128
 BN = 128
 BK = 128
+# Traffic counts and hop distances are integers and the deltas must come out
+# exact; the MXU's default f32 matmul rounds its operands to bf16.
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _swap_kernel(
@@ -48,8 +51,10 @@ def _swap_kernel(
     d_kj = jnp.abs(xkr - xj) + jnp.abs(ykr - yj)  # (BK, BN)
     d_ik = jnp.abs(xi - xkc) + jnp.abs(yi - ykc)  # (BM, BK)
 
-    out_ref[...] += jnp.dot(s_ik_ref[...], d_kj, preferred_element_type=jnp.float32)
-    acc2_ref[...] += jnp.dot(d_ik, s_kj_ref[...], preferred_element_type=jnp.float32)
+    out_ref[...] += jnp.dot(s_ik_ref[...], d_kj, preferred_element_type=jnp.float32,
+                            precision=_EXACT)
+    acc2_ref[...] += jnp.dot(d_ik, s_kj_ref[...], preferred_element_type=jnp.float32,
+                             precision=_EXACT)
 
     @pl.when(kk == nk - 1)
     def _epilogue():

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_local_mesh", "make_mesh_with_layout",
            "batch_axes_of"]
@@ -23,25 +24,24 @@ def make_production_mesh(*, multi_pod: bool = False):
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     n = int(np.prod(shape))
     devices = jax.devices()
+    auto = (AxisType.Auto,) * len(axes)
     if len(devices) == n:
-        return jax.make_mesh(shape, axes)
+        return jax.make_mesh(shape, axes, axis_types=auto)
     if len(devices) < n:
         raise RuntimeError(
             f"need {n} devices for {axes} {shape}, have {len(devices)} — "
             "the dry-run must set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before any jax import")
-    try:  # more devices than needed (single-pod mesh under the 512 flag)
-        return jax.make_mesh(shape, axes, devices=devices[:n])
-    except TypeError:  # older make_mesh without `devices=`
-        from jax.sharding import Mesh
-        return Mesh(np.asarray(devices[:n]).reshape(shape), axes)
+    # More devices than needed (single-pod mesh under the 512 flag).
+    return jax.make_mesh(shape, axes, devices=devices[:n], axis_types=auto)
 
 
 def make_local_mesh(model_parallel: int = 1):
     """Mesh over whatever devices exist (tests/examples on CPU)."""
     n = len(jax.devices())
     assert n % model_parallel == 0
-    return jax.make_mesh((n // model_parallel, model_parallel), ("data", "model"))
+    return jax.make_mesh((n // model_parallel, model_parallel), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_mesh_with_layout(device_order: np.ndarray, *, multi_pod: bool = False):

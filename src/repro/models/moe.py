@@ -118,8 +118,6 @@ def moe_ffn_sharded(
     expert_axis: str = "model",
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Expert-parallel MoE via shard_map (see module docstring)."""
-    from jax.experimental.shard_map import shard_map
-
     n_shards = mesh.shape[expert_axis]
     e_glob = cfg.num_experts
     assert e_glob % n_shards == 0, (e_glob, n_shards)
@@ -139,7 +137,7 @@ def moe_ffn_sharded(
         return out, aux
 
     x_spec = P(batch_axes if batch_axes else None, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(x_spec,
                   P(None, None),  # router replicated
@@ -147,6 +145,6 @@ def moe_ffn_sharded(
                   P(expert_axis, None, None),
                   P(expert_axis, None, None)),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])

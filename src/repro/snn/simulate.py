@@ -30,7 +30,7 @@ from repro.core.graph import Graph, Hypergraph, build_graph, build_hypergraph
 from .lif import LIFParams, lif_run
 from .topology import SNNTopology
 
-__all__ = ["ProfileResult", "profile_snn"]
+__all__ = ["ProfileResult", "input_drive", "profile_snn"]
 
 
 @dataclass
@@ -103,6 +103,15 @@ def _cache_key(topo: SNNTopology, num_steps: int, seed: int, params: LIFParams) 
     return h.hexdigest()[:16]
 
 
+def input_drive(topo: SNNTopology, num_steps: int, seed: int) -> np.ndarray:
+    """(T, N) f32 stimulus: Bernoulli input events on the input layer."""
+    rng = np.random.default_rng(seed)
+    drive = np.zeros((num_steps, topo.num_neurons), dtype=np.float32)
+    events = rng.random((num_steps, topo.input_size)) < topo.input_rate
+    drive[:, : topo.input_size] = events * topo.input_amp
+    return drive
+
+
 def profile_snn(
     topo: SNNTopology,
     num_steps: int = 1200,
@@ -133,12 +142,8 @@ def profile_snn(
 
     t0 = time.perf_counter()
     n = topo.num_neurons
-    rng = np.random.default_rng(seed)
-    drive = np.zeros((num_steps, n), dtype=np.float32)
-    events = rng.random((num_steps, topo.input_size)) < topo.input_rate
-    drive[:, : topo.input_size] = events * topo.input_amp
-
-    raster = lif_run(jnp.asarray(topo.weights), jnp.asarray(drive), params,
+    raster = lif_run(jnp.asarray(topo.weights),
+                     jnp.asarray(input_drive(topo, num_steps, seed)), params,
                      use_pallas=use_pallas, seed=seed)
 
     xadj, adjncy = _synapse_csr(n, topo.syn_src.astype(np.int64), topo.syn_dst.astype(np.int64))

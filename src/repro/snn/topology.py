@@ -23,6 +23,13 @@ import numpy as np
 
 __all__ = ["SNNTopology", "make_snn", "PAPER_SNNS"]
 
+# Synaptic weights sit on a 2^-20 grid, so every sum of them below 2^4 is
+# exact in float32 (a neuron's fan-in weights sum to about `gain` <= 2.5).
+# The synaptic current, and with it every threshold crossing, then does not
+# depend on the order in which numpy, XLA:CPU or the TPU's MXU adds up a
+# step's fired inputs: one raster per seed everywhere.
+_WEIGHT_QUANTUM = 2.0 ** -20
+
 
 @dataclass
 class SNNTopology:
@@ -100,8 +107,9 @@ def _assemble(
         all_src.append(gs)
         all_dst.append(gd)
         # Normalize by fan-in so a fraction ~1/gain of presynaptic activity fires a neuron.
-        fan_in = np.bincount(gd, minlength=n).astype(np.float32)
-        w[gs, gd] = gain / np.maximum(fan_in[gd], 1.0)
+        fan_in = np.bincount(gd, minlength=n).astype(np.float64)
+        w[gs, gd] = np.round(gain / np.maximum(fan_in[gd], 1.0)
+                             / _WEIGHT_QUANTUM) * _WEIGHT_QUANTUM
     return SNNTopology(
         name=name,
         layer_sizes=layer_sizes,

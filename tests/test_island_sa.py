@@ -1,7 +1,8 @@
 """Distributed mapping search: shard_map island SA on a multi-device mesh.
 
 Runs in a subprocess so XLA_FLAGS can force 4 host devices without
-polluting the single-device test session.
+polluting the single-device test session.  ``island_sa`` must work on any
+mesh a caller passes, whatever its axis types.
 """
 import os
 import subprocess
@@ -13,10 +14,11 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import numpy as np
 import jax
+from jax.sharding import AxisType
 from repro.core.mapping import sa_search
 from repro.core.mapping_jax import island_sa
 
-mesh = jax.make_mesh((4,), ("data",))
+mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.{axis_type},))
 rng = np.random.default_rng(0)
 k, cores, w = 12, 16, 4
 c = rng.integers(0, 100, (k, k)).astype(np.float64)
@@ -31,11 +33,20 @@ print(f"ISLAND_OK hop={res.avg_hop:.4f} (serial {ref.avg_hop:.4f})")
 """
 
 
-def test_island_sa_on_four_devices():
+def run_island(axis_type: str) -> None:
     env = dict(os.environ)
     env["PYTHONPATH"] = str(Path(__file__).resolve().parents[1] / "src")
     env.pop("XLA_FLAGS", None)
-    out = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
-                         capture_output=True, text=True, timeout=300)
+    out = subprocess.run([sys.executable, "-c",
+                          SCRIPT.replace("{axis_type}", axis_type)],
+                         env=env, capture_output=True, text=True, timeout=300)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "ISLAND_OK" in out.stdout
+
+
+def test_island_sa_on_four_devices():
+    run_island("Auto")
+
+
+def test_island_sa_on_explicit_mesh():
+    run_island("Explicit")

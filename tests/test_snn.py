@@ -89,3 +89,22 @@ def test_all_paper_snns_build():
         topo = make_snn(name)
         assert topo.num_neurons == int(name.split("_")[1])
         assert topo.weights.shape == (topo.num_neurons,) * 2
+
+
+def test_lif_raster_matches_numpy_reference():
+    """jnp, Pallas and the host numpy recurrence agree bit for bit, on an
+    edge_5120-shaped net whose 0.1 weights make ten inputs hit the threshold
+    exactly: only weights on which every sum is exact make that hold."""
+    from repro.snn.lif import lif_run_ref
+    from repro.snn.simulate import input_drive
+    from repro.snn.topology import _assemble, _local_edges
+
+    topo = _assemble("edge_small", [512, 512, 256],
+                     [_local_edges(512, 512, 2), _local_edges(512, 256, 2)],
+                     gain=2.5, input_rate=0.10, target_spikes=None)
+    drive = input_drive(topo, 300, seed=0)
+    ref = lif_run_ref(topo.weights, drive, LIFParams())
+    assert ref.sum() > 10_000
+    for use_pallas in (False, True):
+        raster = lif_run(topo.weights, drive, LIFParams(), use_pallas=use_pallas)
+        assert np.array_equal(raster, ref)
