@@ -1,0 +1,384 @@
+"""Plain reference of one mapping job, written apart from the toolchain.
+
+It imports nothing of the program.  Given the network, the stimulus and
+the job's own partition and placement, it recomputes every answer the job
+reports, layer by layer:
+
+  profile    the LIF recurrence step by step (event-driven: only the
+             synapses of neurons that fired are read), the transmission
+             trace, and its cut at the Table 1 count
+  partition  capacity and range of every part, and the objective the
+             partitioner minimises (spikes on cut synapses, or the
+             connectivity-1 multicast volume) recounted from the trace
+  map        injectivity of the placement, and the average hop of the
+             job's traffic model recounted from the trace
+  evaluate   a cycle-by-cycle replay of the trace through the XY mesh:
+             every packet steps one link per cycle; each link grants its
+             ``link_capacity`` oldest requests; under multicast one flit
+             per firing forks along the XY tree, a child link requestable
+             the cycle after its parent's grant
+
+Every sum of synaptic weights is exact (weights lie on a 2^-20 grid), so
+the raster is one raster, whatever order a backend adds in.
+"""
+from __future__ import annotations
+
+import numpy as np
+
+from network import Network
+
+_INF = np.iinfo(np.int64).max // 4
+
+
+# ------------------------------------------------------------------ profile
+
+
+def profile(net: Network, drive: np.ndarray, lif: dict) -> dict:
+    """Raster statistics and trace of the kept steps.
+
+    Returns ``num_steps`` (kept), ``fire_counts`` (N,) over the kept steps
+    and ``trace`` as sorted packed keys ``(t * N + src) * N + dst``.
+    """
+    n = net.num_neurons
+    xadj = net.xadj
+    decay = np.float32(lif["decay"])
+    threshold = np.float32(lif["threshold"])
+    v_reset = np.float32(lif["v_reset"])
+    refractory = np.int32(lif["refractory"])
+    v = np.zeros(n, dtype=np.float32)
+    refr = np.zeros(n, dtype=np.int32)
+    fired_prev = np.empty(0, dtype=np.int64)
+    target = net.target_spikes
+    steps: list[np.ndarray] = []  # fired neuron ids per step
+    cum = 0
+    reached = None  # first step at which the count reaches the target
+    for t in range(drive.shape[0]):
+        syn = _synapses_of(fired_prev, xadj)
+        current = np.bincount(net.syn_dst[syn], weights=net.syn_w[syn],
+                              minlength=n).astype(np.float32) + drive[t]
+        active = refr <= 0
+        v = np.where(active, decay * v + current, v)
+        fired = active & (v >= threshold)
+        v = np.where(fired, v_reset, v)
+        refr = np.where(fired, refractory, np.maximum(refr - 1, 0)).astype(np.int32)
+        fired_prev = np.flatnonzero(fired)
+        steps.append(fired_prev)
+        cum += int((xadj[fired_prev + 1] - xadj[fired_prev]).sum())
+        if target is not None and reached is None and cum >= target:
+            reached = t
+        if target is not None and cum > target:
+            break  # the trace is cut at `reached`; later steps are dropped
+    kept = steps[:reached + 1] if (target is not None and cum > target) else steps
+    fire_counts = np.zeros(n, dtype=np.int64)
+    keys = []
+    for t, ids in enumerate(kept):
+        fire_counts[ids] += 1
+        syn = _synapses_of(ids, xadj)
+        keys.append((t * np.int64(n) + net.syn_src[syn]) * n + net.syn_dst[syn])
+    trace = np.sort(np.concatenate(keys)) if keys else np.empty(0, np.int64)
+    return {"num_steps": len(kept), "fire_counts": fire_counts, "trace": trace}
+
+
+def _synapses_of(neurons: np.ndarray, xadj: np.ndarray) -> np.ndarray:
+    """Indices of the outgoing synapses of ``neurons`` (CSR gather)."""
+    starts = xadj[neurons]
+    lens = xadj[neurons + 1] - starts
+    total = int(lens.sum())
+    if total == 0:
+        return np.empty(0, dtype=np.int64)
+    offs = np.concatenate([[0], np.cumsum(lens)[:-1]])
+    return np.repeat(starts - offs, lens) + np.arange(total)
+
+
+def trace_keys(trace_t, trace_src, trace_dst, n: int) -> np.ndarray:
+    """A program's trace in the reference's packed, sorted form."""
+    t = np.asarray(trace_t, dtype=np.int64)
+    return np.sort((t * n + np.asarray(trace_src, np.int64)) * n
+                   + np.asarray(trace_dst, np.int64))
+
+
+def unpack(keys: np.ndarray, n: int) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    return keys // (n * n), (keys // n) % n, keys % n
+
+
+def set_mismatch(a: np.ndarray, b: np.ndarray) -> int:
+    """Size of the symmetric difference of two sorted duplicate-free arrays."""
+    return int(a.shape[0] + b.shape[0]
+               - 2 * np.intersect1d(a, b, assume_unique=True).shape[0])
+
+
+# ---------------------------------------------------------------- partition
+
+
+def partition_violations(part: np.ndarray, k: int, capacity: int,
+                         num_cores: int) -> int:
+    """Neurons outside [0, k), parts over capacity, and k over the cores."""
+    part = np.asarray(part, dtype=np.int64)
+    bad = int(((part < 0) | (part >= k)).sum())
+    loads = np.bincount(part[(part >= 0) & (part < k)], minlength=k)
+    return bad + int((loads > capacity).sum()) + int(k > num_cores)
+
+
+def cut_spikes(net: Network, fire_counts: np.ndarray, part: np.ndarray) -> int:
+    """Spikes carried on synapses whose ends lie in different parts."""
+    cut = part[net.syn_src] != part[net.syn_dst]
+    return int(fire_counts[net.syn_src[cut]].sum())
+
+
+def multicast_volume(net: Network, fire_counts: np.ndarray,
+                     part: np.ndarray) -> int:
+    """Sum over firing neurons of fires x (distinct parts reached beyond
+    the neuron's own)."""
+    part = np.asarray(part, dtype=np.int64)
+    k = int(part.max()) + 1
+    pairs = np.unique(net.syn_src * k + part[net.syn_dst])
+    src, dest_part = pairs // k, pairs % k
+    remote = dest_part != part[src]
+    return int(fire_counts[src[remote]].sum())
+
+
+# --------------------------------------------------------------------- map
+
+
+def placement_violations(placement: np.ndarray, num_cores: int) -> int:
+    placement = np.asarray(placement, dtype=np.int64)
+    out = int(((placement < 0) | (placement >= num_cores)).sum())
+    return out + int(placement.shape[0] - np.unique(placement).shape[0])
+
+
+def packets(keys: np.ndarray, n: int, part: np.ndarray,
+            cast: str) -> tuple[np.ndarray, np.ndarray, np.ndarray, int]:
+    """The job's packets by its traffic model, as (t, src neuron, dest part)
+    remote packets and the count of part-local deliveries.
+
+    Unicast: one packet per transmission.  Multicast: one packet per
+    distinct (firing, destination part), a firing being (t, src neuron).
+    """
+    t, src, dst = unpack(keys, n)
+    ps, pd = part[src], part[dst]
+    local = ps == pd
+    t, src, pd = t[~local], src[~local], pd[~local]
+    if cast == "multicast":
+        k = int(part.max()) + 1
+        u = np.unique((t * n + src) * k + pd)
+        t, src, pd = u // (n * k), (u // k) % n, u % k
+    return t, src, pd, int(local.sum())
+
+
+def avg_hop(keys: np.ndarray, n: int, part: np.ndarray, placement: np.ndarray,
+            mesh_w: int, cast: str) -> float:
+    """Hop-weighted packets over all packets, part-local ones at 0 hops."""
+    _, src, pd, n_local = packets(keys, n, part, cast)
+    a, b = placement[part[src]], placement[pd]
+    hops = np.abs(a % mesh_w - b % mesh_w) + np.abs(a // mesh_w - b // mesh_w)
+    return int(hops.sum()) / max(int(src.shape[0]) + n_local, 1)
+
+
+# ---------------------------------------------------------------- evaluate
+
+
+def link_id(tail: np.ndarray, head: np.ndarray, w: int, h: int) -> np.ndarray:
+    """Directed mesh link ids: east links first (by row, then column), then
+    west, south (by column, then row) and north."""
+    tx, ty, hx, hy = tail % w, tail // w, head % w, head // w
+    east = (w - 1) * h
+    south = 2 * (w - 1) * h
+    north = south + w * (h - 1)
+    return np.select(
+        [hx == tx + 1, hx == tx - 1, hy == ty + 1],
+        [ty * (w - 1) + tx, east + ty * (w - 1) + hx, south + tx * (h - 1) + ty],
+        north + tx * (h - 1) + hy)
+
+
+def _xy_next(cur: np.ndarray, dst: np.ndarray, w: int) -> np.ndarray:
+    """The next core on the XY route: x first, then y."""
+    cx, dx = cur % w, dst % w
+    step_x = np.sign(dx - cx)
+    step_y = np.sign(dst // w - cur // w) * w
+    return cur + np.where(step_x != 0, step_x, step_y)
+
+
+def _inject_cycles(t: np.ndarray, src_core: np.ndarray, ncores: int,
+                   inject_capacity: int) -> np.ndarray:
+    """The r-th injection from a core in a step enters at r // capacity;
+    entries are ranked in the order given."""
+    key = t * ncores + src_core
+    order = np.argsort(key, kind="stable")
+    sk = key[order]
+    first = np.concatenate([[True], sk[1:] != sk[:-1]])
+    start = np.maximum.accumulate(np.where(first, np.arange(sk.shape[0]), 0))
+    rank = np.empty(sk.shape[0], dtype=np.int64)
+    rank[order] = np.arange(sk.shape[0]) - start
+    return rank // inject_capacity
+
+
+def _grants(tag: np.ndarray, capacity: int) -> np.ndarray:
+    """True for the first ``capacity`` requests of each tag, requests being
+    given in priority order."""
+    order = np.argsort(tag, kind="stable")
+    st = tag[order]
+    first = np.concatenate([[True], st[1:] != st[:-1]])
+    start = np.maximum.accumulate(np.where(first, np.arange(st.shape[0]), 0))
+    go = np.empty(st.shape[0], dtype=bool)
+    go[order] = (np.arange(st.shape[0]) - start) < capacity
+    return go
+
+
+def replay(keys: np.ndarray, n: int, part: np.ndarray, placement: np.ndarray,
+           w: int, h: int, noc: dict, cast: str) -> dict:
+    """Every statistic of the queued NoC replay of the trace."""
+    core = np.asarray(placement, dtype=np.int64)[np.asarray(part, np.int64)]
+    t, src, dst = unpack(keys, n)
+    sc, dc = core[src], core[dst]
+    local = sc == dc
+    n_local = int(local.sum())
+    t, src, sc, dc = t[~local], src[~local], sc[~local], dc[~local]
+    if cast == "multicast":
+        stats = _replay_multicast(t, src, sc, dc, n, w, h, noc)
+    elif cast == "unicast":
+        stats = _replay_unicast(t, sc, dc, w, h, noc)
+    else:
+        raise ValueError(f"unknown cast {cast!r}")
+    e = noc["energy_pj"]
+    traversals = int(stats["per_link_hops"].sum())
+    lat = stats.pop("latency")
+    pkt_t = stats.pop("packet_t")
+    n_noc = int(lat.shape[0])
+    hops = stats.pop("hops")
+    total_hops = int(hops.sum())
+    window_max = np.zeros(int(pkt_t.max()) + 1 if n_noc else 0, dtype=np.int64)
+    np.maximum.at(window_max, pkt_t, lat)
+    stats.update(
+        avg_latency=float(lat.mean()) if n_noc else 0.0,
+        max_latency=int(lat.max()) if n_noc else 0,
+        avg_hop=float(total_hops / max(n_noc, 1)),
+        total_hops=total_hops,
+        edge_variance=float(np.var(stats["per_link_hops"])),
+        dynamic_energy_pj=(float(traversals) * (e["router"] + e["link"])
+                           + float(n_local) * e["local"]),
+        num_noc_spikes=n_noc,
+        num_local_spikes=n_local,
+        cycles_simulated=int(window_max.sum()),
+        cast=cast,
+        link_traversals=traversals,
+        spikes_dropped=0,
+        detour_hops=0,
+    )
+    return stats
+
+
+def _replay_unicast(t, sc, dc, w, h, noc) -> dict:
+    """One packet per transmission, stepped until every packet arrives."""
+    cap = int(noc["link_capacity"])
+    nl = 2 * (w - 1) * h + 2 * w * (h - 1)
+    # Record order within a step: by source core, then destination core.
+    order = np.lexsort((dc, sc, t))
+    t, sc, dc = t[order], sc[order], dc[order]
+    inject = _inject_cycles(t, sc, w * h, int(noc["inject_capacity"]))
+    hops = np.abs(sc % w - dc % w) + np.abs(sc // w - dc // w)
+    # Arbitration priority: earlier injection first, then record order.
+    prio = np.argsort(inject, kind="stable")
+    cur = sc.copy()
+    lat = np.zeros(t.shape[0], dtype=np.int64)
+    per_link = np.zeros(nl, dtype=np.int64)
+    congestion = 0
+    alive = prio  # packets in flight, in priority order
+    cycle = 0
+    while alive.shape[0]:
+        req = alive[inject[alive] <= cycle]
+        if req.shape[0]:
+            nxt = _xy_next(cur[req], dc[req], w)
+            link = link_id(cur[req], nxt, w, h)
+            go = _grants(t[req] * nl + link, cap)
+            congestion += int(req.shape[0] - go.sum())
+            moved = req[go]
+            per_link += np.bincount(link[go], minlength=nl)
+            cur[moved] = nxt[go]
+            lat[moved[cur[moved] == dc[moved]]] = cycle + 1
+            alive = alive[cur[alive] != dc[alive]]
+        cycle += 1
+    return {"latency": lat, "packet_t": t, "hops": hops,
+            "per_link_hops": per_link, "congestion_count": congestion}
+
+
+def _replay_multicast(t, src, sc, dc, n, w, h, noc) -> dict:
+    """One flit per firing, forking along the XY tree of its destinations."""
+    cap = int(noc["link_capacity"])
+    ncores = w * h
+    nl = 2 * (w - 1) * h + 2 * w * (h - 1)
+    # Packets: one per distinct (firing, destination core); a firing is
+    # (t, src neuron), numbered in ascending (t, src) order.
+    u, first = np.unique((t * n + src) * ncores + dc, return_index=True)
+    psrc, pdst = sc[first], u % ncores
+    fids, pf = np.unique(u // ncores, return_inverse=True)
+    f_t = fids // n
+    f_core = np.empty(fids.shape[0], dtype=np.int64)
+    f_core[pf] = psrc
+    inject = _inject_cycles(f_t, f_core, ncores, int(noc["inject_capacity"]))
+    hops = np.abs(psrc % w - pdst % w) + np.abs(psrc // w - pdst // w)
+    # Tree links: the union of the XY routes of a firing's packets, one
+    # entity per (firing, tail core, head core).
+    hop_pkt = np.repeat(np.arange(u.shape[0]), hops)
+    step = np.arange(hop_pkt.shape[0]) - np.repeat(
+        np.concatenate([[0], np.cumsum(hops)[:-1]]), hops)
+    tail, head = _route_hop(psrc[hop_pkt], pdst[hop_pkt], step, w)
+    ent = np.unique((pf[hop_pkt] * ncores + tail) * ncores + head)
+    e_f = ent // (ncores * ncores)
+    e_tail = (ent // ncores) % ncores
+    e_link = link_id(e_tail, ent % ncores, w, h)
+    # An XY tree enters a core at most once: (firing, head) names an entity.
+    enter = e_f * ncores + ent % ncores
+    eorder = np.argsort(enter)
+    enter_sorted = enter[eorder]
+    q = e_f * ncores + e_tail
+    pos = np.minimum(np.searchsorted(enter_sorted, q), ent.shape[0] - 1)
+    parent = np.where((enter_sorted[pos] == q) & (e_tail != f_core[e_f]),
+                      eorder[pos], -1)
+    kids = np.argsort(parent, kind="stable")
+    kids_parent = parent[kids]
+    avail = np.where(parent < 0, inject[e_f], _INF)
+    grant = np.full(ent.shape[0], -1, dtype=np.int64)
+    congestion = 0
+    # Arbitration priority: earlier injection first, then firing order.
+    pending = np.lexsort((e_f, inject[e_f]))
+    cycle = 0
+    while pending.shape[0]:
+        req = pending[avail[pending] <= cycle]
+        if req.shape[0]:
+            go = _grants(f_t[e_f[req]] * nl + e_link[req], cap)
+            congestion += int(req.shape[0] - go.sum())
+            won = req[go]
+            grant[won] = cycle
+            # Children request from the next cycle.
+            avail[kids[_ranges(np.searchsorted(kids_parent, won),
+                               np.searchsorted(kids_parent, won, "right"))]] = cycle + 1
+            pending = pending[grant[pending] < 0]
+        cycle += 1
+    # A packet arrives the cycle after the link into its core is granted.
+    into = eorder[np.searchsorted(enter_sorted, pf * ncores + pdst)]
+    return {"latency": grant[into] + 1, "packet_t": f_t[pf], "hops": hops,
+            "per_link_hops": np.bincount(e_link, minlength=nl),
+            "congestion_count": congestion}
+
+
+def _route_hop(src, dst, step, w):
+    """(tail, head) core of hop ``step`` of the XY route src -> dst."""
+    sx, sy, dx, dy = src % w, src // w, dst % w, dst // w
+    hx = np.abs(dx - sx)
+    sgx, sgy = np.sign(dx - sx), np.sign(dy - sy)
+    horizontal = step < hx
+    tx = np.where(horizontal, sx + sgx * step, dx)
+    ty = np.where(horizontal, sy, sy + sgy * (step - hx))
+    hxn = np.where(horizontal, tx + sgx, tx)
+    hyn = np.where(horizontal, ty, ty + sgy)
+    return ty * w + tx, hyn * w + hxn
+
+
+def _ranges(lo: np.ndarray, hi: np.ndarray) -> np.ndarray:
+    """Concatenation of arange(lo[i], hi[i])."""
+    lens = hi - lo
+    total = int(lens.sum())
+    if total == 0:
+        return np.empty(0, dtype=np.int64)
+    offs = np.concatenate([[0], np.cumsum(lens)[:-1]])
+    return np.repeat(lo - offs, lens) + np.arange(total)

@@ -1,0 +1,44 @@
+"""A cell small enough for the CPU: Smooth_320 on a 3x3 mesh."""
+import copy
+import json
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+
+NETWORK = {"name": "smooth_320", "layers": [160, 160],
+           "connections": [{"kind": "local", "radius": 1}], "gain": 2.0,
+           "weight_quantum": 2.0 ** -20, "input_rate": 0.14, "input_amp": 1.5,
+           "table1_transmissions": 60000}
+
+
+def config(cast: str = "unicast") -> dict:
+    with open(BENCH / "configs" / "edge_5120_unicast_5x5.json") as f:
+        cfg = json.load(f)
+    cfg["network"] = copy.deepcopy(NETWORK)
+    cfg["toolchain"].update(
+        mesh_w=3, mesh_h=3, capacity=64,
+        objective="cut" if cast == "unicast" else "volume", cast=cast,
+        noc_kwargs={"stepper": "jax", "screen": "interpret",
+                    "inject_capacity": 256},
+        # A short search: on the CPU every step of the device scan is an
+        # event of the profiler's trace.
+        mapper_kwargs={"iters": 640})
+    return cfg
+
+
+def traffic() -> dict:
+    with open(BENCH / "traffic" / "table1.json") as f:
+        t = json.load(f)
+    t["num_steps"] = 400
+    return t
+
+
+def run(cast="unicast", trace=False, seed=2**33 + 5, kind="end_to_end"):
+    """One run of the harness at the tiny size, off the chip."""
+    import run as harness
+
+    bench = harness.load_json(harness.ROOT / "BENCHMARK.json")
+    metrics = bench["per_layer" if trace else "end_to_end"]
+    return harness.run_cell(config(cast), traffic(), seed, 0.0, trace, metrics,
+                            require_tpu=False, compile_cache=False,
+                            log=lambda _: None)
