@@ -39,7 +39,6 @@ import contextlib
 import dataclasses
 import json
 import sys
-import time
 from pathlib import Path
 
 import numpy as np
@@ -62,46 +61,43 @@ def check(ok: bool, what: str) -> None:
 
 
 class Meter:
-    """Per-phase wall seconds, XLA compile seconds and device peak bytes."""
+    """Per-phase wall seconds, XLA compile seconds and device peak bytes.
+
+    Each phase runs in a `repro.telemetry` span of its name; its seconds
+    and compile counts are that span's, summed over the program's spans
+    inside it.  ``span`` is the open phase's span."""
 
     def __init__(self, jax):
         self.devices = jax.devices()
-        self.compile_s = 0.0
-        self.compiles = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event: str, duration: float, **_) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += duration
-            self.compiles += 1
+        self.span = None
 
     @contextlib.contextmanager
     def phase(self, name: str):
         """Yield a dict the phase fills with what it reports."""
+        from repro import telemetry
+
         info: dict = {}
-        c0, n0, t0 = self.compile_s, self.compiles, time.perf_counter()
-        yield info
-        seconds = time.perf_counter() - t0
+        with telemetry.span(name) as span:
+            self.span = span
+            yield info
         peak = [d.memory_stats()["peak_bytes_in_use"] for d in self.devices]
-        fields = {"seconds": round(seconds, 3),
-                  "compile_s": round(self.compile_s - c0, 3),
-                  "compiles": self.compiles - n0,
+        fields = {"seconds": round(span.seconds, 3),
+                  "compile_s": round(span.total("compile_s"), 3),
+                  "compiles": int(span.total("compiles")),
+                  "cache_loads": int(span.total("cache_loads")),
                   "peak_bytes": peak[0] if len(peak) == 1 else peak, **info}
         print(f"[{name}] " + " ".join(f"{k}={v}" for k, v in fields.items()),
               flush=True)
 
 
 @contextlib.contextmanager
-def counting(owner, name: str, items=None):
-    """Count the calls through ``owner.name`` (and, with ``items``, the
-    items each call hands over) while the block runs."""
+def counting(owner, name: str):
+    """Count the calls through ``owner.name`` while the block runs."""
     orig = getattr(owner, name)
-    tally = {"calls": 0, "items": 0}
+    tally = {"calls": 0}
 
     def wrapper(*args, **kwargs):
         tally["calls"] += 1
-        if items is not None:
-            tally["items"] += items(*args, **kwargs)
         return orig(*args, **kwargs)
 
     setattr(owner, name, wrapper)
@@ -147,17 +143,18 @@ def toolchain_phase(meter: Meter, prof, kernel: str):
     from repro.core import edge_cut, run_toolchain, validate_partition
     from repro.core import refine_vec
     from repro.kernels import link_load
-    from repro.nocsim import replay_jax
 
     with meter.phase("toolchain") as info, \
             counting(refine_vec, "_degrees_via_kernel") as gains, \
-            counting(link_load, "window_link_loads") as screens, \
-            counting(replay_jax, "joint_stepper_jax",
-                     lambda src, *a, **k: len(src)) as stepped:
+            counting(link_load, "window_link_loads") as screens:
         res = run_toolchain(
             prof, mesh_w=MESH, mesh_h=MESH, capacity=CAPACITY, seed=SEED,
             partition_impl="vec", mapper="sa_jax",
             noc_kwargs={"stepper": "jax", "screen": kernel})
+        # Records stepped on the device: those counted on the evaluate
+        # span that holds a stepper span.
+        stepped = sum(s.parent.counters["stepped"]
+                      for s in meter.span.find("stepper"))
         info.update({f"{k}_s": round(v, 3) for k, v in res.phase_seconds.items()})
         info.update(k=res.partition.k, edge_cut=res.partition.edge_cut,
                     avg_hop=res.mapping.avg_hop,
@@ -165,7 +162,7 @@ def toolchain_phase(meter: Meter, prof, kernel: str):
                     congestion=res.noc.congestion_count,
                     gain_eval_calls=gains["calls"],
                     link_load_screens=screens["calls"],
-                    packets_stepped_on_device=stepped["items"],
+                    packets_stepped_on_device=stepped,
                     path="vec partition | sa_jax scan + swap_delta polish | "
                          f"jax stepper + {kernel} link_load screen")
     part = res.partition
@@ -173,7 +170,7 @@ def toolchain_phase(meter: Meter, prof, kernel: str):
     check(edge_cut(prof.graph, part.part) == part.edge_cut,
           "reported edge cut differs from the partition's")
     check(screens["calls"] > 0, "the link_load screen never ran")
-    check(stepped["items"] > 0, "no packet was stepped on the device")
+    check(stepped > 0, "no packet was stepped on the device")
     return res
 
 

@@ -29,6 +29,7 @@ from typing import TYPE_CHECKING
 
 import numpy as np
 
+from repro import telemetry
 from repro.nocsim import NoCStats, combine_stats, simulate_noc
 from repro.runtime.faults import FaultSchedule, FaultState, heartbeat_detect
 from repro.runtime.health import HeartbeatMonitor
@@ -53,6 +54,9 @@ __all__ = [
     "evaluate_phase",
     "run_toolchain",
 ]
+
+# The phases ``run_toolchain`` times in spans of their name, in order.
+_PHASES = ("partition", "mapping", "evaluate")
 
 
 def phase_seeds(seed: int) -> tuple[int, int, int]:
@@ -550,31 +554,30 @@ def run_toolchain(
             noc_kwargs=dict(noc_kwargs or {}),
         )
     cfg = cfg.resolve(profile.graph.hyper)
-    phase: dict[str, float] = {}
+    fault_phase: dict[str, float] = {}
 
-    t0 = time.perf_counter()
-    pres = partition_phase(profile, cfg)
-    phase["partition"] = time.perf_counter() - t0
-
-    t0 = time.perf_counter()
-    mres, place_objective, traffic, trace_len = mapping_phase(profile, pres, cfg)
-    phase["mapping"] = time.perf_counter() - t0
-
-    t0 = time.perf_counter()
-    if fault_schedule is None:
-        noc = evaluate_phase(profile, pres, mres, cfg)
-        phase["evaluate"] = time.perf_counter() - t0
-        degradation = None
-    else:
-        noc_args = dict(link_capacity=cfg.link_capacity, mode=cfg.noc_mode,
-                        cast=cfg.cast)
-        noc_args.update(cfg.noc_kwargs)
-        noc, degradation = _faulty_replay(
-            profile, pres, mres, cfg.mesh_w, cfg.mesh_h, cfg.capacity,
-            noc_args, phase, fault_schedule, remap_strategy, remap_kwargs,
-            detect_windows, cfg.objective, cfg.cast, place_objective,
-            phase_seeds(cfg.seed)[2],
-        )
+    with telemetry.span("toolchain") as root:
+        with telemetry.span("partition"):
+            pres = partition_phase(profile, cfg)
+        with telemetry.span("mapping"):
+            mres, place_objective, traffic, trace_len = mapping_phase(
+                profile, pres, cfg)
+        if fault_schedule is None:
+            with telemetry.span("evaluate"):
+                noc = evaluate_phase(profile, pres, mres, cfg)
+            degradation = None
+        else:
+            noc_args = dict(link_capacity=cfg.link_capacity, mode=cfg.noc_mode,
+                            cast=cfg.cast)
+            noc_args.update(cfg.noc_kwargs)
+            noc, degradation = _faulty_replay(
+                profile, pres, mres, cfg.mesh_w, cfg.mesh_h, cfg.capacity,
+                noc_args, fault_phase, fault_schedule, remap_strategy,
+                remap_kwargs, detect_windows, cfg.objective, cfg.cast,
+                place_objective, phase_seeds(cfg.seed)[2],
+            )
+    phase = {s.name: s.seconds for s in root.children if s.name in _PHASES}
+    phase.update(fault_phase)
     return ToolchainResult(
         method=cfg.method, snn=profile.name, partition=pres, mapping=mres,
         noc=noc, phase_seconds=phase, objective=cfg.objective, cast=cfg.cast,

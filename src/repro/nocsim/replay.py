@@ -61,6 +61,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro import telemetry
+
 from .energy import EnergyModel
 from .stats import NoCStats, edge_stats
 from .xy import (
@@ -316,6 +318,7 @@ def queued_unicast(
                 spkt = (np.cumsum(stepped) - 1)[pkt[tm]]
     lat = inject + hops  # analytic fast path (exact off overloaded pairs)
     congestion = 0
+    telemetry.count("noc_records", n)
     if stepped.any():
         sidx = np.flatnonzero(stepped)
         if sids is None:  # device screen materialized only dirty windows
@@ -366,15 +369,22 @@ def queued_unicast(
             sids, sstep = sids[keep_t], sstep[keep_t]
             spkt = remap[spkt[keep_t]]
             sidx = sidx[keep_p]
+        telemetry.count("stepped", int(sidx.shape[0]))
         if sidx.shape[0]:
             uwin = np.unique(win[sidx])
             cwin = np.searchsorted(uwin, win[sidx])
             if stepper == "jax":
                 from .replay_jax import joint_stepper_jax
 
-                lat_s, congestion = joint_stepper_jax(
-                    src_core[sidx], dst_core[sidx], inject[sidx], cwin,
-                    w, h, nl, link_capacity, max_cycles_per_window)
+                with telemetry.span("stepper"):
+                    lat_s, congestion = joint_stepper_jax(
+                        src_core[sidx], dst_core[sidx], inject[sidx], cwin,
+                        w, h, nl, link_capacity, max_cycles_per_window)
+                    # The device loop runs until the last packet arrives,
+                    # and each grant moves one packet one hop.
+                    telemetry.count("cycles", int(lat_s.max()))
+                    telemetry.count("grants", int(hops[sidx].sum()))
+                    telemetry.count("blocked", congestion)
             else:
                 lat_s, congestion = _joint_stepper(
                     sids, spkt, sstep, hops[sidx], inject[sidx], cwin,

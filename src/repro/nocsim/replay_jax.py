@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import telemetry
+
 __all__ = ["joint_stepper_jax"]
 
 _SENTINEL = np.int32(2**31 - 1)
@@ -131,6 +133,7 @@ def joint_stepper_jax(
             a = np.concatenate([a, np.zeros(pad, dtype=np.int32)])
         return jnp.asarray(a)
 
+    telemetry.count("lanes", m)
     valid = np.zeros(m, dtype=bool)
     valid[:n] = True
     lat, cong, drained, over = _run(

@@ -18,13 +18,13 @@ reaches the target, so benchmark traffic volumes match the paper.
 from __future__ import annotations
 
 import hashlib
-import time
 from dataclasses import dataclass
 from pathlib import Path
 
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.core.graph import Graph, Hypergraph, build_graph, build_hypergraph
 
 from .lif import LIFParams, lif_run
@@ -140,41 +140,41 @@ def profile_snn(
                 fire_counts=z["fire_counts"], seconds=float(z["seconds"]),
             )
 
-    t0 = time.perf_counter()
-    n = topo.num_neurons
-    raster = lif_run(jnp.asarray(topo.weights),
-                     jnp.asarray(input_drive(topo, num_steps, seed)), params,
-                     use_pallas=use_pallas, seed=seed)
+    with telemetry.span("profile") as root:
+        n = topo.num_neurons
+        with telemetry.span("lif_scan"):
+            raster = lif_run(jnp.asarray(topo.weights),
+                             jnp.asarray(input_drive(topo, num_steps, seed)),
+                             params, use_pallas=use_pallas, seed=seed)
 
-    xadj, adjncy = _synapse_csr(n, topo.syn_src.astype(np.int64), topo.syn_dst.astype(np.int64))
-    trace_t, trace_src, trace_dst = _expand_trace(raster, xadj, adjncy)
+        xadj, adjncy = _synapse_csr(n, topo.syn_src.astype(np.int64), topo.syn_dst.astype(np.int64))
+        trace_t, trace_src, trace_dst = _expand_trace(raster, xadj, adjncy)
 
-    # Truncate at the step where cumulative transmissions reach Table 1's count.
-    if topo.target_spikes is not None and trace_t.shape[0] > topo.target_spikes:
-        step_end = int(trace_t[topo.target_spikes - 1])
-        keep = trace_t <= step_end
-        trace_t, trace_src, trace_dst = trace_t[keep], trace_src[keep], trace_dst[keep]
-        raster = raster[: step_end + 1]
-        num_steps = step_end + 1
+        # Truncate at the step where cumulative transmissions reach Table 1's count.
+        if topo.target_spikes is not None and trace_t.shape[0] > topo.target_spikes:
+            step_end = int(trace_t[topo.target_spikes - 1])
+            keep = trace_t <= step_end
+            trace_t, trace_src, trace_dst = trace_t[keep], trace_src[keep], trace_dst[keep]
+            raster = raster[: step_end + 1]
+            num_steps = step_end + 1
 
-    fire_counts = raster.sum(axis=0).astype(np.int64)
-    # Synapse graph: each directed synapse (i -> j) carried fire_counts[i] spikes.
-    graph = build_graph(
-        n,
-        src=topo.syn_src.astype(np.int64),
-        dst=topo.syn_dst.astype(np.int64),
-        weight=fire_counts[topo.syn_src.astype(np.int64)],
-    )
-    # Multicast view: one hyperedge per source with its destination pin set.
-    graph.hyper = build_hypergraph(
-        n, topo.syn_src.astype(np.int64), topo.syn_dst.astype(np.int64),
-        fire_counts,
-    )
-    seconds = time.perf_counter() - t0
+        fire_counts = raster.sum(axis=0).astype(np.int64)
+        # Synapse graph: each directed synapse (i -> j) carried fire_counts[i] spikes.
+        graph = build_graph(
+            n,
+            src=topo.syn_src.astype(np.int64),
+            dst=topo.syn_dst.astype(np.int64),
+            weight=fire_counts[topo.syn_src.astype(np.int64)],
+        )
+        # Multicast view: one hyperedge per source with its destination pin set.
+        graph.hyper = build_hypergraph(
+            n, topo.syn_src.astype(np.int64), topo.syn_dst.astype(np.int64),
+            fire_counts,
+        )
     result = ProfileResult(
         name=topo.name, graph=graph, trace_t=trace_t, trace_src=trace_src,
         trace_dst=trace_dst, num_neurons=n, num_steps=num_steps,
-        fire_counts=fire_counts, seconds=seconds,
+        fire_counts=fire_counts, seconds=root.seconds,
     )
     if key is not None:
         key.parent.mkdir(parents=True, exist_ok=True)
@@ -182,7 +182,7 @@ def profile_snn(
             key, xadj=graph.xadj, adjncy=graph.adjncy, adjwgt=graph.adjwgt,
             vwgt=graph.vwgt, trace_t=trace_t, trace_src=trace_src,
             trace_dst=trace_dst, num_neurons=n, num_steps=num_steps,
-            fire_counts=fire_counts, seconds=seconds,
+            fire_counts=fire_counts, seconds=result.seconds,
             hxadj=graph.hyper.hxadj, hpins=graph.hyper.hpins,
             hwgt=graph.hyper.hwgt, hsrc=graph.hyper.hsrc,
             hfire=graph.hyper.hfire,
