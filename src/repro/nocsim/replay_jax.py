@@ -1,12 +1,18 @@
 """Optional JAX device path for the joint congested-window stepper.
 
 A ``lax.while_loop`` version of `replay._joint_stepper` for large traces:
-fixed-size state (no compaction), one fused device pass per NoC cycle.
-Grant decisions mirror the numpy stepper exactly — per window-tagged link,
-the ``link_capacity`` oldest-injected packets win, stable by record order —
-so latencies and congestion are identical; only the execution substrate
-differs.  Imported lazily by ``simulate_noc(stepper="jax")`` so the default
-numpy path never pays the JAX import.
+fixed-size state (no compaction), two sorts per NoC cycle.  The first sorts
+the lanes by (window-tagged link, injection cycle, record index) and carries
+the record index, so its outputs are the sorted tags and the permutation;
+grants are decided in that order.  The second sorts the grants back to
+record order, keyed by the permutation.  No gather or scatter runs through
+the permutation: on the TPU either costs far more than a sort of the same
+length.  Grant decisions mirror the numpy stepper exactly — per
+window-tagged link, the ``link_capacity`` oldest-injected packets win,
+stable by record order — so latencies and congestion are identical; only
+the execution substrate differs.  Imported lazily by
+``simulate_noc(stepper="jax")`` so the default numpy path never pays the
+JAX import.
 
 Runs under JAX's default 32-bit ints: the wrapper checks that window-tagged
 link ids, cycles, and the blocked-packet count all fit, and refuses
@@ -76,12 +82,19 @@ def _run(cur, wd, inject, win, valid, *, w: int, h: int, nl: int,
         active = (~arrived) & (inject <= cycle)
         nxt, link = _next_link_jnp(cur, wd, w, h)
         tag = jnp.where(active, win * nl + link, _SENTINEL)
-        order = jnp.lexsort((idx, inject, tag))
-        st = tag[order]
+        # The record index is the last key, so the keys are unique and the
+        # sort needs no stability; its third output is the permutation.
+        st, _, order = lax.sort((tag, inject, idx), num_keys=3,
+                                is_stable=False)
         newg = jnp.concatenate([jnp.ones(1, dtype=bool), st[1:] != st[:-1]])
         start = lax.cummax(jnp.where(newg, idx, 0))
-        go_sorted = ((idx - start) < capacity) & active[order]
-        go = jnp.zeros(n, dtype=bool).at[order].set(go_sorted)
+        # Only inactive lanes carry the sentinel tag: an active one's is
+        # below ``n_cwin * nl``, which the wrapper holds under it.
+        go_sorted = ((idx - start) < capacity) & (st != _SENTINEL)
+        # Back to record order: the permutation's values are unique keys.
+        _, go = lax.sort((order, go_sorted.astype(jnp.int32)), num_keys=1,
+                         is_stable=False)
+        go = go.astype(bool)
         cong = cong + active.sum(dtype=jnp.int32) - go.sum(dtype=jnp.int32)
         # Latch before a 32-bit wrap is possible: per-cycle growth is < n
         # <= 2^30 (guarded in the wrapper), so cong passes 2^30 before it

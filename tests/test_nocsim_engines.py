@@ -94,15 +94,60 @@ def test_congested_windows_actually_step():
     assert jam.avg_latency > jam.avg_hop
 
 
-def test_jax_stepper_matches_ref():
-    pytest.importorskip("jax")
+def hot_link_trace(seed):
+    """Random traffic with a burst over one link: 400 spikes from core 0 to
+    its east neighbour in two time steps, so many packets share an inject
+    cycle on that link and only record order breaks their ties."""
     t, src, dst, part, placement = random_spike_trace(
-        seed=1, n_spikes=800, timesteps=6)
-    ref = simulate_noc(t, src, dst, part, placement, 3, 3, link_capacity=1,
+        seed=seed, n_spikes=300, timesteps=6)
+    r = np.random.default_rng(seed)
+    part = part.copy()
+    part[:10], part[10:20] = 0, 1
+    placement = placement.copy()
+    placement[:2] = 0, 1
+    t = np.concatenate([t, r.integers(2, 4, 400)])
+    src = np.concatenate([src, r.integers(0, 10, 400)])
+    dst = np.concatenate([dst, r.integers(10, 20, 400)])
+    order = np.argsort(t, kind="stable")
+    return t[order], src[order], dst[order], part, placement
+
+
+@pytest.mark.parametrize("link_capacity", [1, 2, 3])
+@pytest.mark.parametrize("seed", [1, 7])
+@pytest.mark.parametrize("trace", ["random", "hot_link"])
+def test_jax_stepper_matches_ref(trace, seed, link_capacity):
+    pytest.importorskip("jax")
+    from repro import telemetry
+
+    if trace == "random":
+        args = random_spike_trace(seed=seed, n_spikes=800, timesteps=6)
+    else:
+        args = hot_link_trace(seed)
+    ref = simulate_noc(*args, 3, 3, link_capacity=link_capacity,
                        engine="ref")
-    new = simulate_noc(t, src, dst, part, placement, 3, 3, link_capacity=1,
-                       engine="batched", stepper="jax")
+    with telemetry.span("t_jax_stepper") as root:
+        new = simulate_noc(*args, 3, 3, link_capacity=link_capacity,
+                           engine="batched", stepper="jax")
     assert stats_equal(ref, new) == []
+    (stepper,) = root.find("stepper")
+    # Some lanes are padding: the stepped count is not a power of two.
+    assert 0 < root.counters["stepped"] < stepper.counters["lanes"]
+    assert new.congestion_count > 0
+
+
+def test_jax_stepper_has_no_gather_or_scatter():
+    """The stepper's loop reorders lanes by sorts alone: a gather or a
+    scatter through the sort's permutation costs the TPU far more."""
+    jax = pytest.importorskip("jax")
+    from repro.nocsim.replay_jax import _run
+
+    n, w = 1000, 3
+    ints = [jax.ShapeDtypeStruct((n,), np.int32) for _ in range(4)]
+    text = _run.lower(*ints, jax.ShapeDtypeStruct((n,), np.bool_), w=w, h=w,
+                      nl=link_count(w, w), capacity=2,
+                      max_cycles=1000).as_text()
+    assert "stablehlo.sort" in text
+    assert "gather" not in text and "scatter" not in text
 
 
 def test_screen_backends_do_not_change_results():
