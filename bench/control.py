@@ -1,7 +1,7 @@
 """Controls and faults: the timed path broken underneath, to show that the
 comparison in `check.py` fails when it should.
 
-    python3 bench/control.py --workload <cell> --seeds 3 4 5 --control bf16
+    python3 bench/control.py --workload <cell> --seeds 3 4 5 --control bf16_emul
 
 runs the cell once per seed (a window of one job) with the control or
 fault installed, and prints each run's failing comparisons.  The
@@ -11,13 +11,16 @@ Controls, the reference put in the program's place at a lower precision
 than the configuration states (float32 synaptic sums at HIGHEST):
 
   bf16       the LIF recurrence with its synaptic sums at the backend's
-             ``Precision.DEFAULT`` (one bf16 pass on a TPU)
+             ``Precision.DEFAULT``: no control on a v5e, whose compiler
+             makes the spike vector's product with the weights an f32
+             multiply-reduce at any precision; ``bf16_emul`` is the
+             control there
   bf16_emul  the same with the weights rounded to bf16 written out, so
              that it reads the same on any backend
   high_emul  the weights split into their two leading bf16 parts, as three
              bf16 passes (``Precision.HIGH``) take them: no control, since
              the 2^-20 weight grid splits exactly (a test shows it)
-  analytic   the program's own approximate NoC path (``noc_mode=
+  analytic   the program's own approximate NoC path (``mode=
              "analytic"``: latency = hops, no queueing) in place of the
              queued replay
 
@@ -25,11 +28,23 @@ Faults:
 
   state_unchanged  each LIF step starts from the initial state: potentials
                    and spikes are never carried to the next step
-  half_batch       the replay scores every other packet record only
+  half_batch       each replay scores every other packet record only
   alter_partition  one neuron moves to another part after partitioning
   alter_placement  two parts swap cores after the placement is scored
-  alter_replay     the replay reports one more cycle of latency on one
+  alter_replay     each replay reports one more cycle of latency on one
                    packet
+
+Faults of a replay under a fault schedule:
+
+  drop_delivered    a packet whose XY and YX routes are both blocked is
+                    delivered by YX, through the blocked link
+  detour_dropped    a packet whose XY route is blocked is dropped, though
+                    its YX route is clean
+  detour_xy         a detoured packet is stepped along its XY route
+  dead_core_kept    after each repair one part with neurons of a lost core
+                    is placed on that core
+  boundary_moved    the repaired mapping takes over one step late
+  migrated_off      the job reports one more neuron migrated
 """
 from __future__ import annotations
 
@@ -37,6 +52,7 @@ import argparse
 import contextlib
 import dataclasses
 import functools
+import inspect
 import json
 import os
 import sys
@@ -117,14 +133,20 @@ def _lif(precision=None, split=None, carry_state=True):
 
 
 def _phase(name: str, after=None, before=None):
-    """Wrap a toolchain phase: ``before`` rewrites its arguments, ``after``
-    its result."""
+    """Wrap a function of the toolchain, as ``run_toolchain`` resolves it:
+    ``before`` rewrites its arguments, a dict by parameter name, in place;
+    ``after`` rewrites its result."""
     from repro.core import pipeline
 
     def wrap(orig):
+        signature = inspect.signature(orig)
+
         def phase(*args, **kwargs):
             if before is not None:
-                args = before(*args)
+                bound = signature.bind(*args, **kwargs)
+                bound.apply_defaults()
+                before(bound.arguments)
+                args, kwargs = bound.args, bound.kwargs
             out = orig(*args, **kwargs)
             return after(out) if after is not None else out
         return phase
@@ -132,15 +154,74 @@ def _phase(name: str, after=None, before=None):
     return _patched(pipeline, name, wrap)
 
 
-def _analytic(profile, pres, mres, cfg):
-    return profile, pres, mres, dataclasses.replace(cfg, noc_mode="analytic")
+def _analytic(a: dict) -> None:
+    a["mode"] = "analytic"
 
 
-def _half(profile, pres, mres, cfg):
-    half = dataclasses.replace(profile, trace_t=profile.trace_t[::2],
-                               trace_src=profile.trace_src[::2],
-                               trace_dst=profile.trace_dst[::2])
-    return half, pres, mres, cfg
+def _half(a: dict) -> None:
+    for name in ("trace_t", "trace_src", "trace_dst"):
+        a[name] = a[name][::2]
+
+
+def _routes(yx_blocked: bool):
+    """Every YX escape route reads as blocked, or as clean."""
+    from repro.nocsim import sim
+
+    def wrap(orig):
+        def routes_blocked(src, dst, w, h, blocked, order=None):
+            if order is None:
+                return orig(src, dst, w, h, blocked)
+            return np.full(np.shape(src)[0], yx_blocked)
+        return routes_blocked
+
+    return _patched(sim, "routes_blocked", wrap)
+
+
+def _xy_only():
+    from repro.nocsim import sim
+
+    def wrap(orig):
+        def queued_unicast(*args, **kwargs):
+            return orig(*args, **{**kwargs, "order": None})
+        return queued_unicast
+
+    return _patched(sim, "queued_unicast", wrap)
+
+
+def _left_on_dead(part, placement, dead, res):
+    """The repair with the part that took in the first neuron of a lost
+    core put back on that core."""
+    core = np.asarray(placement)[np.asarray(part)]
+    lost = np.flatnonzero(dead[core])
+    if not lost.shape[0]:
+        return res
+    new = np.array(res.placement)  # a full permutation of the cores
+    p = res.part[lost[0]]
+    q = np.flatnonzero(new == core[lost[0]])[0]
+    new[[p, q]] = new[[q, p]]
+    return dataclasses.replace(res, placement=new)
+
+
+def _remap(after):
+    from repro.core import pipeline
+
+    def wrap(orig):
+        def incremental_remap(graph, part, placement, dead, *args, **kwargs):
+            res = orig(graph, part, placement, dead, *args, **kwargs)
+            return after(part, placement, dead, res)
+        return incremental_remap
+
+    return _patched(pipeline, "incremental_remap", wrap)
+
+
+def _late(a: dict) -> None:
+    a["detect_windows"] += 1
+
+
+def _one_more_migrated(out):
+    noc, degradation = out
+    return noc, {**degradation,
+                 "neurons_migrated": degradation["neurons_migrated"] + 1}
 
 
 def _move_one(pres):
@@ -165,12 +246,18 @@ CONTROLS = {
     "bf16": lambda: _lif("default"),
     "high_emul": lambda: _lif(split="high"),
     "bf16_emul": lambda: _lif(split="bf16"),
-    "analytic": lambda: _phase("evaluate_phase", before=_analytic),
+    "analytic": lambda: _phase("simulate_noc", before=_analytic),
     "state_unchanged": lambda: _lif(carry_state=False),
-    "half_batch": lambda: _phase("evaluate_phase", before=_half),
+    "half_batch": lambda: _phase("simulate_noc", before=_half),
     "alter_partition": lambda: _phase("partition_phase", after=_move_one),
     "alter_placement": lambda: _phase("mapping_phase", after=_swap_two),
-    "alter_replay": lambda: _phase("evaluate_phase", after=_one_more_cycle),
+    "alter_replay": lambda: _phase("simulate_noc", after=_one_more_cycle),
+    "drop_delivered": lambda: _routes(yx_blocked=False),
+    "detour_dropped": lambda: _routes(yx_blocked=True),
+    "detour_xy": _xy_only,
+    "dead_core_kept": lambda: _remap(_left_on_dead),
+    "boundary_moved": lambda: _phase("_faulty_replay", before=_late),
+    "migrated_off": lambda: _phase("_faulty_replay", after=_one_more_migrated),
 }
 
 

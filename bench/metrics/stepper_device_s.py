@@ -1,4 +1,4 @@
-"""Device seconds per job of the JAX replay stepper (``jit__run``,
+"""Device seconds per traced job of the JAX replay stepper (``jit__run``,
 `nocsim/replay_jax.py`)."""
 
 import trace_reduce
@@ -10,4 +10,5 @@ def read(ctx: dict):
     if ctx["trace"] is None:
         return None
     seconds = trace_reduce.module_seconds(ctx["trace"], MODULE)
-    return None if seconds is None else seconds / len(ctx["jobs"])
+    jobs = len(ctx["trace"]["job_busy_s"])
+    return None if seconds is None or not jobs else seconds / jobs

@@ -17,6 +17,11 @@ reports, layer by layer:
              ``link_capacity`` oldest requests; under multicast one flit
              per firing forks along the XY tree, a child link requestable
              the cycle after its parent's grant
+  faults     cores and links that die at given steps: the trace replays
+             in segments between the events (`fault_segments`), each under
+             the failures in force on the mapping the job used there
+             (`replay_faulty`, unicast), and the segments' statistics
+             combine by their definitions (`combine`)
 
 Every sum of synaptic weights is exact (weights lie on a 2^-20 grid), so
 the raster is one raster, whatever order a backend adds in.
@@ -198,6 +203,13 @@ def _xy_next(cur: np.ndarray, dst: np.ndarray, w: int) -> np.ndarray:
     return cur + np.where(step_x != 0, step_x, step_y)
 
 
+def _yx_next(cur: np.ndarray, dst: np.ndarray, w: int) -> np.ndarray:
+    """The next core on the YX route: y first, then x."""
+    step_y = np.sign(dst // w - cur // w) * w
+    step_x = np.sign(dst % w - cur % w)
+    return cur + np.where(step_y != 0, step_y, step_x)
+
+
 def _inject_cycles(t: np.ndarray, src_core: np.ndarray, ncores: int,
                    inject_capacity: int) -> np.ndarray:
     """The r-th injection from a core in a step enters at r // capacity;
@@ -239,6 +251,12 @@ def replay(keys: np.ndarray, n: int, part: np.ndarray, placement: np.ndarray,
         stats = _replay_unicast(t, sc, dc, w, h, noc)
     else:
         raise ValueError(f"unknown cast {cast!r}")
+    return _finish(stats, n_local, noc, cast)
+
+
+def _finish(stats: dict, n_local: int, noc: dict, cast: str) -> dict:
+    """The statistics of a replay from its packets' latencies and hops and
+    its per-link traversals."""
     e = noc["energy_pj"]
     traversals = int(stats["per_link_hops"].sum())
     lat = stats.pop("latency")
@@ -267,13 +285,16 @@ def replay(keys: np.ndarray, n: int, part: np.ndarray, placement: np.ndarray,
     return stats
 
 
-def _replay_unicast(t, sc, dc, w, h, noc) -> dict:
-    """One packet per transmission, stepped until every packet arrives."""
+def _replay_unicast(t, sc, dc, w, h, noc, yx=None) -> dict:
+    """One packet per transmission, stepped until every packet arrives;
+    packets flagged in ``yx`` route Y first, the others X first."""
     cap = int(noc["link_capacity"])
     nl = 2 * (w - 1) * h + 2 * w * (h - 1)
+    if yx is None:
+        yx = np.zeros(t.shape[0], dtype=bool)
     # Record order within a step: by source core, then destination core.
     order = np.lexsort((dc, sc, t))
-    t, sc, dc = t[order], sc[order], dc[order]
+    t, sc, dc, yx = t[order], sc[order], dc[order], yx[order]
     inject = _inject_cycles(t, sc, w * h, int(noc["inject_capacity"]))
     hops = np.abs(sc % w - dc % w) + np.abs(sc // w - dc // w)
     # Arbitration priority: earlier injection first, then record order.
@@ -287,7 +308,8 @@ def _replay_unicast(t, sc, dc, w, h, noc) -> dict:
     while alive.shape[0]:
         req = alive[inject[alive] <= cycle]
         if req.shape[0]:
-            nxt = _xy_next(cur[req], dc[req], w)
+            nxt = np.where(yx[req], _yx_next(cur[req], dc[req], w),
+                           _xy_next(cur[req], dc[req], w))
             link = link_id(cur[req], nxt, w, h)
             go = _grants(t[req] * nl + link, cap)
             congestion += int(req.shape[0] - go.sum())
@@ -382,3 +404,187 @@ def _ranges(lo: np.ndarray, hi: np.ndarray) -> np.ndarray:
         return np.empty(0, dtype=np.int64)
     offs = np.concatenate([[0], np.cumsum(lens)[:-1]])
     return np.repeat(lo - offs, lens) + np.arange(total)
+
+
+# ------------------------------------------------------------------ faults
+
+
+def link_of(tail: int, head: int, w: int, h: int) -> int:
+    """The id of the mesh link from core ``tail`` to its neighbour ``head``."""
+    tx, ty, hx, hy = tail % w, tail // w, head % w, head // w
+    if not (0 <= tail < w * h and 0 <= head < w * h
+            and abs(tx - hx) + abs(ty - hy) == 1):
+        raise ValueError(f"no mesh link from core {tail} to core {head}")
+    return int(link_id(np.int64(tail), np.int64(head), w, h))
+
+
+def fault_state(events: list[dict], t: int, w: int,
+                h: int) -> tuple[np.ndarray, np.ndarray]:
+    """(dead cores, blocked links) once every event at or before step ``t``
+    has happened.  An event kills cores (``kind`` "core", ``ids``) or one
+    link (``kind`` "link", named by its ``from`` and ``to`` cores).  A
+    blocked link is a dead one or one whose tail or head router is dead."""
+    dead = np.zeros(w * h, dtype=bool)
+    blocked = np.zeros(2 * (w - 1) * h + 2 * w * (h - 1), dtype=bool)
+    for ev in events:
+        if int(ev["t"]) > t:
+            continue
+        if ev["kind"] == "core":
+            dead[list(ev["ids"])] = True
+        elif ev["kind"] == "link":
+            blocked[link_of(int(ev["from"]), int(ev["to"]), w, h)] = True
+        else:
+            raise ValueError(f"unknown fault kind {ev['kind']!r}")
+    cores = np.arange(w * h)
+    x, y = cores % w, cores // w
+    for dx, dy in ((1, 0), (-1, 0), (0, 1), (0, -1)):
+        ok = (x + dx >= 0) & (x + dx < w) & (y + dy >= 0) & (y + dy < h)
+        tail = cores[ok]
+        head = tail + dx + dy * w
+        blocked[link_id(tail, head, w, h)[dead[tail] | dead[head]]] = True
+    return dead, blocked
+
+
+def fault_segments(events: list[dict], detect_windows: int, t_end: int,
+                   w: int, h: int) -> list[dict]:
+    """The segments ``[lo, hi)`` of steps that a faulted replay runs, in
+    order.  The replay runs up to each event step.  After a step that kills
+    a core, the next ``detect_windows`` steps (up to the next event at
+    most) replay on the stale mapping under the new failures (``lag``);
+    then the mapping is repaired, and ``repaired`` marks the first segment
+    on the new one.  A step that kills only links changes the failures
+    alone.  Events at or after ``t_end`` change nothing.  Each segment
+    carries the failures in force (``dead``, ``blocked``) and the cores
+    dead at the last repair before it (``avoid``; None before any)."""
+    times = sorted({int(e["t"]) for e in events})
+    dead, blocked = fault_state([], 0, w, h)
+    segments: list[dict] = []
+    cursor, avoid, repaired = 0, None, False
+
+    def add(lo: int, hi: int, lag: bool) -> None:
+        segments.append({"lo": lo, "hi": hi, "lag": lag, "repaired": repaired,
+                         "dead": dead, "blocked": blocked, "avoid": avoid})
+
+    for i, te in enumerate(times):
+        if te >= t_end:
+            break
+        add(cursor, te, False)
+        repaired = False
+        cursor = max(cursor, te)
+        dead, blocked = fault_state(events, te, w, h)
+        if not any(e["kind"] == "core" for e in events if int(e["t"]) == te):
+            continue
+        end = min([cursor + max(detect_windows, 0), t_end] + times[i + 1:i + 2])
+        add(cursor, end, True)
+        cursor, avoid, repaired = end, dead, True
+    add(cursor, t_end, False)
+    return segments
+
+
+def replayed(segments: list[dict], keys: np.ndarray, n: int) -> list[dict]:
+    """The segments that hold records of the trace (sorted packed keys),
+    each with its ``keys``, ``records`` and first and last step; a segment
+    with none is not replayed, and passes its ``repaired`` mark on."""
+    out, repaired = [], False
+    per_step = np.int64(n) * n
+    for seg in segments:
+        lo, hi = np.searchsorted(keys, [seg["lo"] * per_step,
+                                        seg["hi"] * per_step])
+        repaired |= seg["repaired"]
+        if hi <= lo:
+            continue
+        seg_keys = keys[lo:hi]
+        out.append({**seg, "repaired": repaired, "keys": seg_keys,
+                    "records": int(hi - lo),
+                    "t_first": int(seg_keys[0] // per_step),
+                    "t_last": int(seg_keys[-1] // per_step)})
+        repaired = False
+    return out
+
+
+def replay_faulty(keys: np.ndarray, n: int, part: np.ndarray,
+                  placement: np.ndarray, w: int, h: int, noc: dict,
+                  dead: np.ndarray, blocked: np.ndarray) -> dict:
+    """Every statistic of the queued unicast replay under failures.  A
+    core-local delivery on a dead core is dropped; so is a remote packet
+    with a dead endpoint.  A packet whose XY route crosses no blocked link
+    goes XY; otherwise it goes YX if that route is clean, and its route
+    hops count as ``detour_hops``; otherwise it is dropped."""
+    core = np.asarray(placement, dtype=np.int64)[np.asarray(part, np.int64)]
+    t, src, dst = unpack(keys, n)
+    sc, dc = core[src], core[dst]
+    local = sc == dc
+    dropped = int((local & dead[sc]).sum())
+    n_local = int(local.sum()) - dropped
+    t, sc, dc = t[~local], sc[~local], dc[~local]
+    xy_bad = _route_blocked(sc, dc, blocked, w, h, _xy_next)
+    yx_bad = _route_blocked(sc, dc, blocked, w, h, _yx_next)
+    go = ~(dead[sc] | dead[dc]) & ~(xy_bad & yx_bad)
+    yx = go & xy_bad
+    stats = _finish(_replay_unicast(t[go], sc[go], dc[go], w, h, noc, yx[go]),
+                    n_local, noc, "unicast")
+    hops = np.abs(sc % w - dc % w) + np.abs(sc // w - dc // w)
+    stats.update(spikes_dropped=dropped + int((~go).sum()),
+                 detour_hops=int(hops[yx].sum()))
+    return stats
+
+
+def _route_blocked(src, dst, blocked, w, h, step) -> np.ndarray:
+    """True where the route that ``step`` walks from src to dst crosses a
+    blocked link."""
+    cur = src.copy()
+    hit = np.zeros(src.shape[0], dtype=bool)
+    idx = np.flatnonzero(cur != dst)
+    while idx.shape[0]:
+        nxt = step(cur[idx], dst[idx], w)
+        hit[idx] |= blocked[link_id(cur[idx], nxt, w, h)]
+        cur[idx] = nxt
+        idx = idx[nxt != dst[idx]]
+    return hit
+
+
+_SUMMED = ("total_hops", "congestion_count", "dynamic_energy_pj",
+           "num_noc_spikes", "num_local_spikes", "cycles_simulated",
+           "link_traversals", "spikes_dropped", "detour_hops")
+
+
+def combine(parts: list[dict]) -> dict:
+    """The statistics of a replay made in segments, from the segments'
+    statistics by their definitions: counts, energy, hops, cycles and the
+    per-link histogram sum; the average latency re-weights by NoC packets;
+    the maximum is the largest; the edge variance is the summed
+    histogram's.  A single segment is its own record."""
+    if len(parts) == 1:
+        return parts[0]
+    out = dict(parts[0])
+    for key in _SUMMED:
+        out[key] = sum(p[key] for p in parts)
+    n_noc = out["num_noc_spikes"]
+    per_link = np.sum([p["per_link_hops"] for p in parts], axis=0)
+    out.update(
+        avg_latency=(sum(p["avg_latency"] * p["num_noc_spikes"] for p in parts)
+                     / n_noc if n_noc else 0.0),
+        max_latency=max(p["max_latency"] for p in parts),
+        avg_hop=out["total_hops"] / n_noc if n_noc else 0.0,
+        per_link_hops=per_link,
+        edge_variance=float(np.var(per_link)),
+    )
+    return out
+
+
+def remap_violations(part: np.ndarray, placement: np.ndarray, capacity: int,
+                     num_cores: int, dead: np.ndarray) -> int:
+    """Of a repaired mapping: neurons outside the placement's parts or on a
+    dead core, parts over capacity, placements off the mesh or sharing a
+    core, and parts with neurons placed on a dead core."""
+    part = np.asarray(part, dtype=np.int64)
+    placement = np.asarray(placement, dtype=np.int64)
+    k = placement.shape[0]
+    inside = (part >= 0) & (part < k)
+    loads = np.bincount(part[inside], minlength=k)
+    bad = int((~inside).sum()) + int((loads > capacity).sum())
+    bad += placement_violations(placement, num_cores)
+    on_mesh = (placement >= 0) & (placement < num_cores)
+    on_dead = on_mesh & dead[np.clip(placement, 0, num_cores - 1)]
+    bad += int(on_dead[part[inside]].sum()) + int((on_dead & (loads > 0)).sum())
+    return bad

@@ -17,12 +17,16 @@ Set-up builds the network, then runs one job on the run's own input, which
 compiles every program the window uses; ``setup_s`` runs from process
 start to its end.  The window then runs jobs back to back and closes at the
 end of the first job that ends after ``--seconds``.  Its compile count is
-printed before the result.  Afterwards the last job is compared with the
-plain reference (`check.py`), and each metric named in ``BENCHMARK.json``
-for the cell is read by its reader, ``bench/metrics/<name>.py``: the
-end-to-end metrics with ``--trace 0``, the per-layer ones with ``--trace
-1``, where the window runs under the JAX profiler with a host span around
-the window, the profile and each phase of the toolchain.
+printed before the result.  A configuration's ``toolchain.fault_schedule``
+(cores and links that die at given steps) makes each job replay its trace
+in segments and re-map after each lost core; the harness records what
+each replay ran on, for the check.  Afterwards the last job is compared
+with the plain reference (`check.py`), and each metric named in
+``BENCHMARK.json`` for the cell is read by its reader,
+``bench/metrics/<name>.py``: the end-to-end metrics with ``--trace 0``, the
+per-layer ones with ``--trace 1``, where the window's first ``TRACE_JOBS``
+jobs run under the JAX profiler with a host span around them, the profile
+and each phase of the toolchain.
 
 The last line of standard output is the result as JSON; the last lines of
 standard error are the numbers compared, each beside its limit.  Without a
@@ -54,7 +58,10 @@ ROOT = BENCH.parent
 # Fixed paths inside the checkout: the cache's path is part of its key.
 CACHE_DIR = ROOT / ".jax_cache"
 TRACE_DIR = ROOT / ".bench_out" / "trace"
-LAYERS = ("profile", "partition", "mapping", "evaluate")
+LAYERS = ("profile", "partition", "mapping", "evaluate", "remap")
+# Window jobs under the profiler, from the first: its trace buffers hold
+# about 8 of the edge cell's jobs and drop the rest.
+TRACE_JOBS = 5
 
 
 class NoChip(RuntimeError):
@@ -121,12 +128,15 @@ class Meter:
 
 def _annotate_phases(jax) -> None:
     """Wrap the toolchain's phase functions, as ``run_toolchain`` resolves
-    them, in host spans named after their layer."""
+    them, in host spans named after their layer; each re-map under a fault
+    schedule is a ``remap`` span.  `recording` opens the ``evaluate`` span
+    around each NoC replay."""
     from repro.core import pipeline
 
     for layer, attr in (("partition", "partition_phase"),
                         ("mapping", "mapping_phase"),
-                        ("evaluate", "evaluate_phase")):
+                        ("remap", "incremental_remap"),
+                        ("remap", "scratch_remap")):
         fn = getattr(pipeline, attr)
 
         def wrapped(*args, _fn=fn, _layer=layer, **kwargs):
@@ -147,8 +157,73 @@ def make_topology(net: network.Network):
         target_spikes=net.target_spikes)
 
 
+def toolchain_kwargs(toolchain: dict) -> dict:
+    """``run_toolchain``'s keywords from a configuration's ``toolchain``.
+
+    Its ``fault_schedule`` is a list of events: ``{"t": 150, "kind":
+    "core", "ids": [12]}``, or ``{"t": 100, "kind": "link", "from": 16,
+    "to": 17}`` for the link from one core to its neighbour.  It becomes
+    the program's `FaultSchedule`, each link named by its id.
+    """
+    if "fault_schedule" not in toolchain:
+        return toolchain
+    import numpy as np
+
+    from repro.nocsim.xy import link_count, link_endpoints
+    from repro.runtime.faults import FaultEvent, FaultSchedule
+
+    w, h = int(toolchain["mesh_w"]), int(toolchain["mesh_h"])
+    tail, head = link_endpoints(np.arange(link_count(w, h)), w, h)
+    events = []
+    for ev in toolchain["fault_schedule"]:
+        ids = ev.get("ids", ())
+        if ev["kind"] == "link":
+            ids = np.flatnonzero((tail == ev["from"]) & (head == ev["to"]))
+            if ids.shape[0] != 1:
+                raise ValueError(f"no mesh link from core {ev['from']} "
+                                 f"to core {ev['to']}")
+        events.append(FaultEvent(int(ev["t"]), ev["kind"],
+                                 tuple(int(i) for i in ids)))
+    return {**toolchain, "fault_schedule": FaultSchedule(events)}
+
+
+@contextlib.contextmanager
+def recording(span):
+    """Wrap each NoC replay the toolchain makes in an ``evaluate`` span, and
+    yield the list of what each replayed: how many records, its first and
+    last step, and the partition and placement it replayed them on.  A
+    fault-free job makes one replay; under a fault schedule the toolchain
+    replays its trace in segments, each on the mapping then in force."""
+    import numpy as np
+
+    from repro.core import pipeline
+
+    replay = pipeline.simulate_noc
+    segments: list[dict] = []
+
+    def recorded(trace_t, trace_src, trace_dst, part, placement, *args,
+                 **kwargs):
+        t = np.asarray(trace_t)
+        if t.shape[0]:
+            segments.append({"records": int(t.shape[0]),
+                             "t_first": int(t.min()), "t_last": int(t.max()),
+                             "part": np.array(part, dtype=np.int64),
+                             "placement": np.array(placement, dtype=np.int64)})
+        with span("evaluate"):
+            return replay(trace_t, trace_src, trace_dst, part, placement,
+                          *args, **kwargs)
+
+    pipeline.simulate_noc = recorded
+    try:
+        yield segments
+    finally:
+        pipeline.simulate_noc = replay
+
+
 def run_job(topo, params, config: dict, traffic: dict, seed: int, span):
-    """One job: profile the network, then run the toolchain on the profile."""
+    """One job: profile the network, then run the toolchain on the profile.
+    Returns the profile, the toolchain's result, the job's record and its
+    NoC replays as `recording` lists them."""
     from repro.core import run_toolchain
     from repro.snn import profile_snn
 
@@ -157,7 +232,9 @@ def run_job(topo, params, config: dict, traffic: dict, seed: int, span):
         prof = profile_snn(topo, num_steps=int(traffic["num_steps"]),
                            seed=seed, params=params)
     t1 = time.perf_counter()
-    res = run_toolchain(prof, seed=seed, **config["toolchain"])
+    with recording(span) as segments:
+        res = run_toolchain(prof, seed=seed,
+                            **toolchain_kwargs(config["toolchain"]))
     record = {"job_s": time.perf_counter() - t0, "profile_s": t1 - t0,
               **{f"{k}_s": v for k, v in res.phase_seconds.items()},
               "kept_steps": int(prof.num_steps),
@@ -166,7 +243,10 @@ def run_job(topo, params, config: dict, traffic: dict, seed: int, span):
               "objective": check.reported_objective(
                   res, config["toolchain"]["objective"]),
               "avg_hop": float(res.mapping.avg_hop)}
-    return prof, res, record
+    if res.degradation is not None:
+        record["neurons_migrated"] = int(res.degradation["neurons_migrated"])
+        record["spikes_dropped"] = int(res.noc.spikes_dropped)
+    return prof, res, record, segments
 
 
 def run_cell(config: dict, traffic: dict, seed: int, seconds: float,
@@ -177,6 +257,7 @@ def run_cell(config: dict, traffic: dict, seed: int, seconds: float,
     import jax
 
     devices = jax.devices()
+    t_devices = time.perf_counter()
     if require_tpu and (devices[0].platform != "tpu" or len(devices) < chips):
         raise NoChip(f"needs {chips} TPU chip(s); JAX found {len(devices)} "
                      f"{devices[0].platform} device(s)")
@@ -200,39 +281,48 @@ def run_cell(config: dict, traffic: dict, seed: int, seconds: float,
     def no_span(_):
         return contextlib.nullcontext()
 
-    prof, res, _ = run_job(topo, params, config, traffic, seed, no_span)
+    t_network = time.perf_counter()
+    prof, res, _, _ = run_job(topo, params, config, traffic, seed, no_span)
     setup_s = time.perf_counter() - _T0
     compiled0, loaded0 = meter.counts()
-    log(f"[setup] seconds={setup_s} compiles={compiled0} "
+    # Where set-up goes: start to the devices (imports, the backend's
+    # start), building the network, and the warm job.
+    log(f"[setup] seconds={setup_s} devices_s={t_devices - _T0} "
+        f"network_s={t_network - t_devices} "
+        f"warm_job_s={_T0 + setup_s - t_network} compiles={compiled0} "
         f"cache_loads={loaded0} compile_and_load_s={meter.seconds}")
     del prof, res
 
     span = jax.profiler.TraceAnnotation if trace else no_span
-    if trace:
-        _annotate_phases(jax)
-        shutil.rmtree(TRACE_DIR, ignore_errors=True)
-        # Host spans at level 1 only: the harness's own annotations and
-        # JAX's dispatch, without every thread pool event or Python call.
-        options = jax.profiler.ProfileOptions()
-        options.host_tracer_level = 1
-        options.python_tracer_level = 0
-        options.enable_hlo_proto = False
-        jax.profiler.start_trace(str(TRACE_DIR), profiler_options=options)
     jobs, summaries = [], []
-    with span(trace_reduce.WINDOW):
+    with contextlib.ExitStack() as traced:
+        if trace:
+            _annotate_phases(jax)
+            shutil.rmtree(TRACE_DIR, ignore_errors=True)
+            # Host spans at level 1 only: the harness's own annotations and
+            # JAX's dispatch, without every thread pool event or Python call.
+            options = jax.profiler.ProfileOptions()
+            options.host_tracer_level = 1
+            options.python_tracer_level = 0
+            options.enable_hlo_proto = False
+            jax.profiler.start_trace(str(TRACE_DIR), profiler_options=options)
+            traced.callback(jax.profiler.stop_trace)
+            traced.enter_context(span(trace_reduce.WINDOW))
         t_start = time.perf_counter()
         while True:
-            prof, res, record = run_job(topo, params, config, traffic, seed, span)
+            prof, res, record, segments = run_job(topo, params, config,
+                                                  traffic, seed, span)
             jobs.append(record)
-            summaries.append(check.job_summary(prof, res, objective))
+            summaries.append(check.job_summary(prof, res, objective, segments))
+            if len(jobs) == TRACE_JOBS:
+                traced.close()  # the traced window's span, then the trace
             if time.perf_counter() - t_start >= seconds:
                 break
             del prof, res
         window_s = time.perf_counter() - t_start
-    if trace:
-        jax.profiler.stop_trace()
     compiled, loaded = meter.counts()
     log(f"[window] jobs={len(jobs)} seconds={window_s} "
+        f"job_s={[j['job_s'] for j in jobs]} "
         f"compiles={compiled - compiled0} cache_loads={loaded - loaded0}")
     used = devices[:chips]
     peak = max(d.memory_stats()["peak_bytes_in_use"] for d in used) \
@@ -241,17 +331,23 @@ def run_cell(config: dict, traffic: dict, seed: int, seconds: float,
     reduced = None
     if trace:
         reduced = trace_reduce.reduce(trace_reduce.load(_xplane()), LAYERS,
-                                      [d.id for d in used])
+                                      [d.id for d in used], job="profile")
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
         log(f"[trace] busy_s={reduced['busy_s']} window_s={reduced['window_s']} "
+            f"job_busy_s={reduced['job_busy_s']} "
             f"modules={trace_reduce.top(reduced['modules'])}")
     gc.collect()
     checks, ref_stats = check.outcome(net, config, traffic, seed, prof, res,
-                                      summaries)
+                                      summaries, segments)
     ok = check.passed(checks)
     failed = sum(1 for s in summaries if not (ok and s == summaries[-1]))
+    complete = reduced is not None and trace_reduce.complete(reduced)
+    if reduced is not None and not complete:
+        log("[trace] a traced job holds under 9/10 of the median job's device "
+            "time: the trace lost events, so no device metric is read")
     ctx = {"setup_s": setup_s, "window_s": window_s, "jobs": jobs,
-           "reference": ref_stats, "trace": reduced, "config": config,
+           "reference": ref_stats, "trace": reduced if complete else None,
+           "config": config,
            "traffic": traffic, "neurons": net.num_neurons,
            "device_kind": devices[0].device_kind}
     values = {}

@@ -22,11 +22,36 @@ CASES = [
 ]
 
 
+# Under the tiny core schedule: (control or fault, a number it must fail).
+FAULT_CASES = [
+    ("bf16_emul", "profile_fires"),
+    ("state_unchanged", "profile_trace"),
+    ("analytic", "noc_avg_latency"),
+    ("half_batch", "noc_num_noc_spikes"),
+    ("alter_replay", "noc_avg_latency"),
+    ("drop_delivered", "noc_spikes_dropped"),
+    ("detour_dropped", "noc_spikes_dropped"),
+    ("detour_xy", "noc_per_link_hops"),
+    ("dead_core_kept", "remap_invalid"),
+    ("boundary_moved", "fault_segments"),
+    ("migrated_off", "remap_migrated"),
+]
+
+
 @pytest.mark.parametrize("name,cast,number", CASES,
                          ids=[f"{c[0]}-{c[1]}" for c in CASES])
 def test_control_is_not_correct(name, cast, number):
     with control.CONTROLS[name]():
         res = tiny.run(cast)
+    assert not res["correct"]
+    assert number in control.failing(res)
+
+
+@pytest.mark.parametrize("name,number", FAULT_CASES,
+                         ids=[c[0] for c in FAULT_CASES])
+def test_control_is_not_correct_under_faults(name, number):
+    with control.CONTROLS[name]():
+        res = tiny.run(faults=tiny.CORES)
     assert not res["correct"]
     assert number in control.failing(res)
 

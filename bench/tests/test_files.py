@@ -39,6 +39,39 @@ def test_metric_reader_loads(name):
     assert callable(mod.read)
 
 
+@pytest.mark.parametrize("faults", ["LINKS_ONLY", "CORES"])
+def test_fault_schedule_loads(faults):
+    """The harness builds the program's schedule from a configuration's
+    events, and the program and the reference agree on what has failed
+    once they all have: the same dead cores and the same blocked links."""
+    import numpy as np
+
+    import reference
+    import run
+    import tiny
+
+    tc = tiny.config(faults=getattr(tiny, faults))["toolchain"]
+    w, h = tc["mesh_w"], tc["mesh_h"]
+    schedule = run.toolchain_kwargs(tc)["fault_schedule"]
+    assert len(schedule) == len(tc["fault_schedule"])
+    last = max(e["t"] for e in tc["fault_schedule"])
+    state = schedule.state_at(last, w, h)
+    dead, blocked = reference.fault_state(tc["fault_schedule"], last, w, h)
+    assert np.array_equal(state.dead_cores, dead)
+    assert np.array_equal(state.blocked_links(), blocked)
+    assert int(state.dead_links.sum()) == sum(
+        e["kind"] == "link" for e in tc["fault_schedule"])
+
+
+def test_a_link_between_cores_that_are_not_neighbours_is_refused():
+    import run
+
+    tc = {"mesh_w": 3, "mesh_h": 3,
+          "fault_schedule": [{"t": 1, "kind": "link", "from": 0, "to": 2}]}
+    with pytest.raises(ValueError, match="no mesh link"):
+        run.toolchain_kwargs(tc)
+
+
 def test_networks_match_make_snn():
     """The benchmark's own network generator makes the Table 1 networks the
     program's `make_snn` makes, synapse for synapse and weight for weight."""

@@ -4,6 +4,7 @@ import json
 
 import pytest
 
+import check
 import tiny
 from conftest import BENCH
 
@@ -19,6 +20,42 @@ def test_untraced_run_is_correct(cast):
     assert all(m["value"] > 0 for m in res["metrics"].values())
     assert list(res)[-1] == "checks"
     assert res["device"]["platform"] == "cpu"
+
+
+@pytest.mark.parametrize("faults", [tiny.LINKS_ONLY, tiny.CORES],
+                         ids=["links", "cores"])
+def test_faulted_run_is_correct(faults):
+    res = tiny.run(faults=faults)
+    assert res["correct"], {k: c for k, c in res["checks"].items() if c["value"]}
+    for name in check.FAULT_CHECKS + [f"noc_{f}" for f in check.NOC_FIELDS]:
+        assert res["checks"][name]["value"] == 0, name
+    assert set(res["metrics"]) == {m["name"] for m in SPEC["end_to_end"]}
+
+
+def test_traced_faulted_run_is_correct():
+    """A traced run under the tiny core schedule stays correct, and the
+    readers of the host phases find their numbers."""
+    res = tiny.run(trace=True, faults=tiny.CORES)
+    assert res["correct"], {k: c for k, c in res["checks"].items() if c["value"]}
+    for name in ("profile_s", "partition_s", "mapping_s", "evaluate_s",
+                 "interpart_share", "avg_hop", "profile_host_s"):
+        assert res["metrics"][name]["value"] > 0, name
+
+
+def test_trace_covers_the_first_jobs(monkeypatch):
+    """The profiler traces the window's first ``TRACE_JOBS`` jobs only; the
+    window runs on untraced."""
+    import run
+
+    monkeypatch.setattr(run, "TRACE_JOBS", 1)
+    lines = []
+    bench = run.load_json(run.ROOT / "BENCHMARK.json")
+    res = run.run_cell(tiny.config(), tiny.traffic(), 2**33 + 7, 30.0, True,
+                       bench["per_layer"], require_tpu=False,
+                       compile_cache=False, log=lines.append)
+    assert res["correct"] and res["attempted"] >= 2
+    trace = next(line for line in lines if line.startswith("[trace]"))
+    assert "job_busy_s=[0.0] " in trace
 
 
 def test_traced_run_reads_the_host_layers():

@@ -105,3 +105,132 @@ def test_violations():
     assert reference.placement_violations(np.array([0, 3]), 4) == 0
     assert reference.placement_violations(np.array([0, 0]), 4) == 1
     assert reference.placement_violations(np.array([0, 4]), 4) == 1
+
+
+FAULTS = [  # (dead cores, dead links by tail and head core) on a 3x3 mesh
+    ([4], []),
+    ([], [(1, 2), (3, 4)]),
+    ([8], [(4, 1), (0, 3)]),
+]
+
+
+@pytest.mark.parametrize("seed", range(3))
+@pytest.mark.parametrize("faults", FAULTS, ids=["core", "links", "both"])
+@pytest.mark.parametrize("engine", ["batched", "ref"])
+def test_faulty_replay_matches_the_program(seed, faults, engine):
+    """Drops, YX detours and the queued replay under failures, against
+    the program's batched and scalar engines: every statistic equal."""
+    from repro.nocsim import simulate_noc
+    from repro.nocsim.energy import EnergyModel
+    from repro.runtime.faults import FaultState
+
+    n, w, h = 60, 3, 3
+    keys, part, placement = _random_job(seed, n=n, w=w, h=h)
+    cores, links = faults
+    events = ([{"t": 0, "kind": "core", "ids": cores}] +
+              [{"t": 0, "kind": "link", "from": a, "to": b} for a, b in links])
+    dead, blocked = reference.fault_state(events, 0, w, h)
+    state = FaultState.none(w, h)
+    state.dead_cores[cores] = True
+    for a, b in links:
+        state.dead_links[reference.link_of(a, b, w, h)] = True
+    t, s, d = reference.unpack(keys, n)
+    got = dataclasses.asdict(simulate_noc(
+        t.astype(np.int32), s.astype(np.int32), d.astype(np.int32), part,
+        placement, w, h, link_capacity=NOC["link_capacity"],
+        inject_capacity=NOC["inject_capacity"], engine=engine,
+        energy=EnergyModel(), faults=state))
+    want = reference.replay_faulty(keys, n, part, placement, w, h, NOC, dead,
+                                   blocked)
+    assert want["spikes_dropped"] > 0
+    for field, value in want.items():
+        if isinstance(value, np.ndarray):
+            assert np.array_equal(got[field], value), field
+        else:
+            assert got[field] == value, field
+
+
+def test_a_detour_goes_y_first():
+    """A packet from core 0 to core 4 of a 3x3 mesh whose XY route's first
+    link (0 -> 1) is dead goes 0 -> 3 -> 4 and counts its 2 hops."""
+    n, w, h = 2, 3, 3
+    keys = np.array([(0 * n + 0) * n + 1])  # step 0, neuron 0 -> neuron 1
+    part, placement = np.array([0, 1]), np.array([0, 4])
+    dead, blocked = reference.fault_state(
+        [{"t": 0, "kind": "link", "from": 0, "to": 1}], 0, w, h)
+    st = reference.replay_faulty(keys, n, part, placement, w, h, NOC, dead,
+                                 blocked)
+    assert (st["detour_hops"], st["spikes_dropped"], st["avg_latency"]) == (2, 0, 2)
+    south, east = reference.link_of(0, 3, w, h), reference.link_of(3, 4, w, h)
+    assert np.flatnonzero(st["per_link_hops"]).tolist() == sorted([south, east])
+
+
+def test_fault_segments_by_hand():
+    """A link dies at 10, a core at 20 and 22, a core past the trace's end:
+    segments [0, 10), [10, 20), the lag [20, 22), an empty one up to the
+    next event, which starts the first repair's mapping, the lag [22, 24),
+    then [24, 30) on the second repair."""
+    events = [{"t": 10, "kind": "link", "from": 0, "to": 1},
+              {"t": 20, "kind": "core", "ids": [4]},
+              {"t": 22, "kind": "core", "ids": [8]},
+              {"t": 40, "kind": "core", "ids": [0]}]
+    segs = reference.fault_segments(events, 2, 30, 3, 3)
+    assert [(s["lo"], s["hi"], s["lag"], s["repaired"]) for s in segs] == [
+        (0, 10, False, False), (10, 20, False, False), (20, 22, True, False),
+        (22, 22, False, True), (22, 24, True, False), (24, 30, False, True)]
+    assert segs[1]["blocked"].sum() == 1 and not segs[1]["dead"].any()
+    assert segs[2]["avoid"] is None and segs[4]["avoid"].tolist() == \
+        segs[2]["dead"].tolist()
+    assert segs[5]["dead"][[4, 8]].all() and not segs[5]["dead"][0]
+
+
+def test_replayed_passes_a_repair_on_past_an_empty_segment():
+    n = 2
+    per_step = n * n
+    keys = np.array([0, 1 * per_step, 5 * per_step + 1])  # steps 0, 1, 5
+    segs = [{"lo": 0, "hi": 2, "repaired": False},
+            {"lo": 2, "hi": 4, "repaired": True},
+            {"lo": 4, "hi": 6, "repaired": False}]
+    out = reference.replayed(segs, keys, n)
+    assert [(s["records"], s["t_first"], s["t_last"], s["repaired"])
+            for s in out] == [(2, 0, 1, False), (1, 5, 5, True)]
+
+
+def test_combine_matches_the_programs_combination():
+    from repro.nocsim import combine_stats
+    from repro.nocsim.stats import NoCStats
+
+    n, w, h = 60, 3, 3
+    parts = []
+    for seed in range(3):
+        keys, part, placement = _random_job(seed, n=n, w=w, h=h)
+        dead, blocked = reference.fault_state(
+            [{"t": 0, "kind": "core", "ids": [seed]}], 0, w, h)
+        parts.append(reference.replay_faulty(keys, n, part, placement, w, h,
+                                             NOC, dead, blocked))
+    want = dataclasses.asdict(combine_stats([NoCStats(**p) for p in parts]))
+    got = reference.combine(parts)
+    for field, value in want.items():
+        if isinstance(value, np.ndarray):
+            assert np.array_equal(got[field], value), field
+        else:
+            assert got[field] == value, field
+
+
+def test_remap_violations():
+    dead = np.array([False, True, False, False])
+    ok = reference.remap_violations(np.array([0, 0, 1]), np.array([0, 2, 1]),
+                                    2, 4, dead)
+    assert ok == 0
+    # Part 1 (one neuron) on dead core 1: the neuron and the part.
+    assert reference.remap_violations(np.array([0, 0, 1]), np.array([0, 1, 2]),
+                                      2, 4, dead) == 2
+    # Over capacity, shared core, off the mesh, a neuron outside the parts.
+    assert reference.remap_violations(np.array([0, 0, 0]), np.array([0, 2]),
+                                      2, 4, dead) == 1
+    assert reference.remap_violations(np.array([0, 1]), np.array([2, 2]),
+                                      2, 4, dead) == 1
+    assert reference.remap_violations(np.array([0, 1]), np.array([0, 4]),
+                                      2, 4, dead) == 1
+    assert reference.remap_violations(np.array([0, 3]), np.array([0, 2]),
+                                      2, 4, dead) == 1

@@ -80,3 +80,26 @@ def test_reduce_averages_over_the_cells_chips():
     assert red["device_planes"] == 2
     assert red["busy_s"] == pytest.approx(20e-9)
     assert red["idle"] == pytest.approx({"host": 80e-9})
+
+
+@pytest.mark.parametrize("late_ops,complete", [
+    ([("fusion", 60, 80)], True),   # both jobs busy 20 ns
+    ([("fusion", 60, 65)], False),  # the second job's events lost
+    ([], False),                    # the second job has none
+])
+def test_jobs_hold_the_same_device_time(late_ops, complete):
+    """Jobs run from one outermost ``profile`` span to the next (a nested
+    span of the same name opens no job); every job is the same, so one
+    that holds less device time than the median job is a trace that lost
+    events."""
+    pd = _Trace([
+        ("/host:CPU", [("python3", [("window", 0, 100), ("profile", 0, 20),
+                                    ("profile", 1, 19), ("profile", 50, 70),
+                                    ("profile", 120, 130)])]),
+        ("/device:TPU:0", [("XLA Ops", [("fusion", 10, 30)] + late_ops)]),
+    ])
+    red = trace_reduce.reduce(pd, ("profile",), [0], job="profile")
+    busy = 20e-9 if complete else sum(e - s for _, s, e in late_ops) * 1e-9
+    assert red["job_busy_s"] == pytest.approx([20e-9, busy])
+    assert trace_reduce.complete(red) is complete
+    assert trace_reduce.reduce(pd, ("profile",), [0])["job_busy_s"] == []

@@ -11,7 +11,16 @@ NETWORK = {"name": "smooth_320", "layers": [160, 160],
            "table1_transmissions": 60000}
 
 
-def config(cast: str = "unicast") -> dict:
+# Fault schedules on the tiny job's 3x3 mesh, where its six parts sit on
+# cores 4, 7, 2, 5, 8 and 1: links that carry traffic, and two busy cores.
+LINKS_ONLY = [{"t": 60, "kind": "link", "from": 1, "to": 2},
+              {"t": 120, "kind": "link", "from": 4, "to": 1}]
+CORES = [{"t": 50, "kind": "link", "from": 1, "to": 2},
+         {"t": 100, "kind": "core", "ids": [4]},
+         {"t": 200, "kind": "core", "ids": [8]}]
+
+
+def config(cast: str = "unicast", faults: list | None = None) -> dict:
     with open(BENCH / "configs" / "edge_5120_unicast_5x5.json") as f:
         cfg = json.load(f)
     cfg["network"] = copy.deepcopy(NETWORK)
@@ -23,6 +32,13 @@ def config(cast: str = "unicast") -> dict:
         # A short search: on the CPU every step of the device scan is an
         # event of the profiler's trace.
         mapper_kwargs={"iters": 640})
+    if faults is not None:
+        # The fault-aware replay runs on the host backends only.
+        cfg["toolchain"].update(
+            fault_schedule=copy.deepcopy(faults), detect_windows=2,
+            remap_strategy="incremental",
+            noc_kwargs={"stepper": "numpy", "screen": "numpy",
+                        "inject_capacity": 256})
     return cfg
 
 
@@ -33,12 +49,12 @@ def traffic() -> dict:
     return t
 
 
-def run(cast="unicast", trace=False, seed=2**33 + 5, kind="end_to_end"):
+def run(cast="unicast", trace=False, seed=2**33 + 5, faults=None):
     """One run of the harness at the tiny size, off the chip."""
     import run as harness
 
     bench = harness.load_json(harness.ROOT / "BENCHMARK.json")
     metrics = bench["per_layer" if trace else "end_to_end"]
-    return harness.run_cell(config(cast), traffic(), seed, 0.0, trace, metrics,
-                            require_tpu=False, compile_cache=False,
+    return harness.run_cell(config(cast, faults), traffic(), seed, 0.0, trace,
+                            metrics, require_tpu=False, compile_cache=False,
                             log=lambda _: None)

@@ -16,6 +16,13 @@ chips are left out) this module takes:
   idle        idle device seconds in the window, by the innermost layer
               span the host was in at the middle of each gap (``host``
               where it was in none)
+  job_busy_s  busy seconds of each job in the window, a job running from
+              the start of one outermost ``job`` span to the start of the
+              next (or the window's end), averaged over the planes
+
+Every job of a run is the same job, so each holds the same device time;
+`complete` reads a job with less as a trace that lost events (the profiler
+drops trace buffers past its limit).
 """
 from __future__ import annotations
 
@@ -55,9 +62,11 @@ def clip(intervals, lo: float, hi: float) -> list[tuple[float, float]]:
     return [(max(s, lo), min(e, hi)) for s, e in intervals if e > lo and s < hi]
 
 
-def reduce(pd, spans: tuple[str, ...], device_ids: list[int]) -> dict:
+def reduce(pd, spans: tuple[str, ...], device_ids: list[int],
+           job: str | None = None) -> dict:
     """The numbers above from a loaded trace; ``spans`` names the layer
-    spans to attribute idle time to, ``device_ids`` the chips used."""
+    spans to attribute idle time to, ``device_ids`` the chips used, ``job``
+    the span that opens each job."""
     host = []
     for plane in pd.planes:
         if plane.name.startswith("/host:"):
@@ -68,6 +77,12 @@ def reduce(pd, spans: tuple[str, ...], device_ids: list[int]) -> dict:
         raise ValueError("the trace holds no window span")
     _, w0, w1 = max(windows, key=lambda ev: ev[2] - ev[1])
     layer = sorted((ev for ev in host if ev[0] in spans), key=lambda ev: ev[1])
+    starts, end = [], w0  # the outermost ``job`` spans in the window
+    for name, s, e in layer:
+        if name == job and end <= s < w1:
+            starts.append(s)
+            end = e
+    job_busy = [0.0] * len(starts)
     busy, modules, ops = [], defaultdict(float), defaultdict(float)
     idle = defaultdict(float)
     planes = [p for p in pd.planes if (m := _DEVICE_PLANE.match(p.name))
@@ -86,6 +101,9 @@ def reduce(pd, spans: tuple[str, ...], device_ids: list[int]) -> dict:
                     modules[_SUFFIX.sub("", name)] += (ce - cs) * 1e-9
         merged = merge(op_iv)
         busy.append(sum(e - s for s, e in merged) * 1e-9)
+        for i, (lo, hi) in enumerate(zip(starts, starts[1:] + [w1])):
+            job_busy[i] += sum(e - s for s, e in clip(merged, lo, hi)) \
+                * 1e-9 / len(planes)
         for gs, ge in _gaps(merged, w0, w1):
             idle[_span_at(layer, (gs + ge) / 2)] += (ge - gs) * 1e-9 / len(planes)
     return {
@@ -95,7 +113,15 @@ def reduce(pd, spans: tuple[str, ...], device_ids: list[int]) -> dict:
         "modules": dict(modules),
         "ops": dict(ops),
         "idle": dict(idle),
+        "job_busy_s": job_busy,
     }
+
+
+def complete(reduced: dict) -> bool:
+    """Whether every job of the window holds at least nine tenths of the
+    median job's device time."""
+    busy = sorted(reduced["job_busy_s"])
+    return not busy or busy[0] >= 0.9 * busy[len(busy) // 2]
 
 
 def _gaps(merged, lo: float, hi: float) -> list[tuple[float, float]]:
