@@ -108,18 +108,25 @@ def outcome(net: network.Network, config: dict, traffic: dict, seed: int,
     stats = None
     if checks["partition_invalid"] == 0 and checks["placement_invalid"] == 0 \
             and placement.shape[0] == k:
-        checks["placement_avg_hop"] = abs(res.mapping.avg_hop - reference.avg_hop(
-            trace, n, part, placement, int(tc["mesh_w"]), cast))
+        w, h = int(tc["mesh_w"]), int(tc["mesh_h"])
+        # Under multicast every firing of a neuron reaches the same cores:
+        # the reference counts and replays the trace from its firings.
+        hop = (reference.multicast_avg_hop(net, fires, part, placement, w)
+               if cast == "multicast"
+               else reference.avg_hop(trace, n, part, placement, w, cast))
+        checks["placement_avg_hop"] = abs(res.mapping.avg_hop - hop)
         noc = {"link_capacity": tc["link_capacity"],
                "inject_capacity": tc["noc_kwargs"]["inject_capacity"],
                "energy_pj": config["energy_pj"]}
         if "fault_schedule" in tc:
             stats = _faulted(checks, tc, noc, trace, n, part, placement, res,
                              segments)
+        elif cast == "multicast":
+            stats = reference.replay_firings(net, ref["firings"], part,
+                                             placement, w, h, noc)
         else:
-            stats = reference.replay(trace, n, part, placement,
-                                     int(tc["mesh_w"]), int(tc["mesh_h"]),
-                                     noc, cast)
+            stats = reference.replay(trace, n, part, placement, w, h, noc,
+                                     cast)
         got = dataclasses.asdict(res.noc)
         for f in NOC_FIELDS:
             checks[f"noc_{f}"] = None if stats is None else _gap(got[f], stats[f])

@@ -2,18 +2,23 @@
 file and a stimulus from a traffic file and the run's seed.
 
 A configuration's ``network`` block names its layers, the connectivity
-between each pair of consecutive layers (``local`` receptive fields on 2D
-grids, or ``full``), the fan-in gain, the weight grid, the stimulus rate and
-amplitude, and the Table 1 transmission count, at which the profile's trace
-is cut.  The construction follows the one the SNEAP paper's networks use
-(CARLsim image-processing tutorials for Smooth/Edge, a fully connected
-MLP), with weights on a 2^-20 grid so that every synaptic sum is exact in
-float32.  The same seed gives the same stimulus.
+between each pair of consecutive layers, the fan-in gain, the weight grid,
+the stimulus rate and amplitude, the Table 1 transmission count, at which
+the profile's trace is cut, and the ``seed`` (default 0) of the generator
+that random connectivity draws from.  Each connectivity ``kind`` is a file
+of its own, ``bench/networks/<kind>.py``, whose ``connect(spec, n_src,
+n_dst, rng)`` returns the (source, destination) index pairs of one pair of
+layers; one generator is handed to each pair in order.  The construction
+follows the one the SNEAP paper's networks use (CARLsim image-processing
+tutorials for Smooth/Edge, a fully connected MLP, random layers), with
+weights on a 2^-20 grid so that every synaptic sum is exact in float32.
+The same seed gives the same stimulus.
 """
 from __future__ import annotations
 
-import math
+import importlib.util
 from dataclasses import dataclass
+from pathlib import Path
 
 import numpy as np
 
@@ -42,40 +47,19 @@ class Network:
         return np.concatenate([[0], np.cumsum(counts)]).astype(np.int64)
 
 
-def _grid(n: int) -> tuple[int, int]:
-    """Near-square (h, w) with h * w == n."""
-    h = int(math.sqrt(n))
-    while n % h:
-        h -= 1
-    return h, n // h
+NETWORKS = Path(__file__).resolve().parent / "networks"
 
 
-def _local(n_src: int, n_dst: int, radius: int) -> tuple[np.ndarray, np.ndarray]:
-    """Receptive fields: each source feeds the (2r+1)^2 block around its
-    position scaled into the destination grid."""
-    hs, ws = _grid(n_src)
-    hd, wd = _grid(n_dst)
-    src_r, src_c = np.divmod(np.arange(n_src), ws)
-    ctr_r = (src_r * hd) // hs
-    ctr_c = (src_c * wd) // ws
-    srcs, dsts = [], []
-    for dr in range(-radius, radius + 1):
-        for dc in range(-radius, radius + 1):
-            rr, cc = ctr_r + dr, ctr_c + dc
-            ok = (rr >= 0) & (rr < hd) & (cc >= 0) & (cc < wd)
-            srcs.append(np.nonzero(ok)[0])
-            dsts.append(rr[ok] * wd + cc[ok])
-    return np.concatenate(srcs), np.concatenate(dsts)
-
-
-def _connect(spec: dict, n_src: int, n_dst: int) -> tuple[np.ndarray, np.ndarray]:
-    kind = spec["kind"]
-    if kind == "local":
-        return _local(n_src, n_dst, int(spec["radius"]))
-    if kind == "full":
-        return (np.repeat(np.arange(n_src), n_dst),
-                np.tile(np.arange(n_dst), n_src))
-    raise ValueError(f"unknown connectivity {kind!r}")
+def connectivity(kind: str):
+    """The ``connect`` of ``bench/networks/<kind>.py``."""
+    path = NETWORKS / f"{kind}.py"
+    if not path.is_file():
+        raise ValueError(f"unknown connectivity {kind!r}: no file "
+                         f"bench/networks/{kind}.py")
+    spec = importlib.util.spec_from_file_location(f"bench_network_{kind}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.connect
 
 
 def build_network(spec: dict) -> Network:
@@ -88,9 +72,10 @@ def build_network(spec: dict) -> Network:
     quantum = float(spec["weight_quantum"])
     gain = float(spec["gain"])
     weights = np.zeros((n, n), dtype=np.float32)
+    rng = np.random.default_rng(int(spec.get("seed", 0)))
     all_src, all_dst = [], []
     for li, conn in enumerate(spec["connections"]):
-        s, d = _connect(conn, sizes[li], sizes[li + 1])
+        s, d = connectivity(conn["kind"])(conn, sizes[li], sizes[li + 1], rng)
         gs = np.asarray(s, dtype=np.int64) + offsets[li]
         gd = np.asarray(d, dtype=np.int64) + offsets[li + 1]
         # Fan-in normalisation, rounded to the grid.

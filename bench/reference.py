@@ -11,12 +11,15 @@ reports, layer by layer:
              partitioner minimises (spikes on cut synapses, or the
              connectivity-1 multicast volume) recounted from the trace
   map        injectivity of the placement, and the average hop of the
-             job's traffic model recounted from the trace
+             job's traffic model recounted from the trace (under
+             multicast from the fire counts and the synapses: every
+             firing of a neuron reaches the same parts)
   evaluate   a cycle-by-cycle replay of the trace through the XY mesh:
              every packet steps one link per cycle; each link grants its
              ``link_capacity`` oldest requests; under multicast one flit
              per firing forks along the XY tree, a child link requestable
-             the cycle after its parent's grant
+             the cycle after its parent's grant, and the check replays
+             the trace from its firings, building each neuron's tree once
   faults     cores and links that die at given steps: the trace replays
              in segments between the events (`fault_segments`), each under
              the failures in force on the mapping the job used there
@@ -32,17 +35,15 @@ import numpy as np
 
 from network import Network
 
-_INF = np.iinfo(np.int64).max // 4
-
-
 # ------------------------------------------------------------------ profile
 
 
 def profile(net: Network, drive: np.ndarray, lif: dict) -> dict:
     """Raster statistics and trace of the kept steps.
 
-    Returns ``num_steps`` (kept), ``fire_counts`` (N,) over the kept steps
-    and ``trace`` as sorted packed keys ``(t * N + src) * N + dst``.
+    Returns ``num_steps`` (kept), ``fire_counts`` (N,) over the kept steps,
+    ``firings`` as sorted keys ``t * N + src`` and ``trace`` as sorted
+    packed keys ``(t * N + src) * N + dst``.
     """
     n = net.num_neurons
     xadj = net.xadj
@@ -75,13 +76,18 @@ def profile(net: Network, drive: np.ndarray, lif: dict) -> dict:
             break  # the trace is cut at `reached`; later steps are dropped
     kept = steps[:reached + 1] if (target is not None and cum > target) else steps
     fire_counts = np.zeros(n, dtype=np.int64)
-    keys = []
+    keys, firings = [], []
     for t, ids in enumerate(kept):
         fire_counts[ids] += 1
+        firings.append(t * np.int64(n) + ids)
         syn = _synapses_of(ids, xadj)
         keys.append((t * np.int64(n) + net.syn_src[syn]) * n + net.syn_dst[syn])
-    trace = np.sort(np.concatenate(keys)) if keys else np.empty(0, np.int64)
-    return {"num_steps": len(kept), "fire_counts": fire_counts, "trace": trace}
+    trace = (np.sort(np.concatenate(keys), kind="stable") if keys
+             else np.empty(0, np.int64))
+    firings = (np.concatenate(firings) if firings
+               else np.empty(0, np.int64))
+    return {"num_steps": len(kept), "fire_counts": fire_counts,
+            "firings": firings, "trace": trace}
 
 
 def _synapses_of(neurons: np.ndarray, xadj: np.ndarray) -> np.ndarray:
@@ -97,9 +103,11 @@ def _synapses_of(neurons: np.ndarray, xadj: np.ndarray) -> np.ndarray:
 
 def trace_keys(trace_t, trace_src, trace_dst, n: int) -> np.ndarray:
     """A program's trace in the reference's packed, sorted form."""
-    t = np.asarray(trace_t, dtype=np.int64)
-    return np.sort((t * n + np.asarray(trace_src, np.int64)) * n
-                   + np.asarray(trace_dst, np.int64))
+    keys = np.asarray(trace_t, dtype=np.int64) * n
+    keys += np.asarray(trace_src)
+    keys *= n
+    keys += np.asarray(trace_dst)
+    return np.sort(keys, kind="stable")
 
 
 def unpack(keys: np.ndarray, n: int) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -108,6 +116,8 @@ def unpack(keys: np.ndarray, n: int) -> tuple[np.ndarray, np.ndarray, np.ndarray
 
 def set_mismatch(a: np.ndarray, b: np.ndarray) -> int:
     """Size of the symmetric difference of two sorted duplicate-free arrays."""
+    if np.array_equal(a, b):
+        return 0
     return int(a.shape[0] + b.shape[0]
                - 2 * np.intersect1d(a, b, assume_unique=True).shape[0])
 
@@ -236,6 +246,23 @@ def _grants(tag: np.ndarray, capacity: int) -> np.ndarray:
     return go
 
 
+def multicast_avg_hop(net: Network, fire_counts: np.ndarray, part: np.ndarray,
+                      placement: np.ndarray, mesh_w: int) -> float:
+    """`avg_hop` under multicast of the trace the fire counts send over the
+    network's synapses: every firing of a neuron sends one packet to each
+    distinct remote part its synapses reach."""
+    part = np.asarray(part, dtype=np.int64)
+    k = int(part.max()) + 1
+    local = part[net.syn_src] == part[net.syn_dst]
+    pairs = np.unique(net.syn_src[~local] * k + part[net.syn_dst[~local]])
+    src, pd = pairs // k, pairs % k
+    a, b = placement[part[src]], placement[pd]
+    hops = np.abs(a % mesh_w - b % mesh_w) + np.abs(a // mesh_w - b // mesh_w)
+    n_local = int(fire_counts[net.syn_src[local]].sum())
+    packets = int(fire_counts[src].sum())
+    return int((fire_counts[src] * hops).sum()) / max(packets + n_local, 1)
+
+
 def replay(keys: np.ndarray, n: int, part: np.ndarray, placement: np.ndarray,
            w: int, h: int, noc: dict, cast: str) -> dict:
     """Every statistic of the queued NoC replay of the trace."""
@@ -246,12 +273,42 @@ def replay(keys: np.ndarray, n: int, part: np.ndarray, placement: np.ndarray,
     n_local = int(local.sum())
     t, src, sc, dc = t[~local], src[~local], sc[~local], dc[~local]
     if cast == "multicast":
-        stats = _replay_multicast(t, src, sc, dc, n, w, h, noc)
+        # Each firing (t, src neuron) forks along a tree of its own, to
+        # each distinct destination core once; firings in (t, src) order.
+        ncores = w * h
+        u = np.unique((t * n + src) * ncores + dc)
+        fids, starts = np.unique(u // ncores, return_index=True)
+        stats = _replay_multicast(fids // n, np.arange(fids.shape[0]),
+                                  core[fids % n], np.append(starts, u.shape[0]),
+                                  u % ncores, w, h, noc)
     elif cast == "unicast":
         stats = _replay_unicast(t, sc, dc, w, h, noc)
     else:
         raise ValueError(f"unknown cast {cast!r}")
     return _finish(stats, n_local, noc, cast)
+
+
+def replay_firings(net: Network, firings: np.ndarray, part: np.ndarray,
+                   placement: np.ndarray, w: int, h: int, noc: dict) -> dict:
+    """`replay` under multicast of the trace that ``firings`` (sorted
+    ``t * N + src``, as `profile` gives them) send over the network's
+    synapses, from the firings: every firing of a neuron reaches the same
+    cores, so each neuron's tree is built once."""
+    n, ncores = net.num_neurons, w * h
+    core = np.asarray(placement, dtype=np.int64)[np.asarray(part, np.int64)]
+    sc, dc = core[net.syn_src], core[net.syn_dst]
+    local = sc == dc
+    # The distinct remote destination cores of each neuron, by neuron.
+    pairs = np.unique(net.syn_src[~local] * ncores + dc[~local])
+    ptr = np.concatenate([[0], np.cumsum(np.bincount(pairs // ncores,
+                                                     minlength=n))])
+    f_src = firings % n
+    n_local = int(np.bincount(net.syn_src[local], minlength=n)[f_src].sum())
+    # A firing with no remote packet injects nothing.
+    sends = ptr[f_src + 1] > ptr[f_src]
+    stats = _replay_multicast(firings[sends] // n, f_src[sends], core, ptr,
+                              pairs % ncores, w, h, noc)
+    return _finish(stats, n_local, noc, "multicast")
 
 
 def _finish(stats: dict, n_local: int, noc: dict, cast: str) -> dict:
@@ -323,63 +380,97 @@ def _replay_unicast(t, sc, dc, w, h, noc, yx=None) -> dict:
             "per_link_hops": per_link, "congestion_count": congestion}
 
 
-def _replay_multicast(t, src, sc, dc, n, w, h, noc) -> dict:
-    """One flit per firing, forking along the XY tree of its destinations."""
+def _replay_multicast(f_t, f_tree, tree_core, tree_ptr, tree_dest, w, h,
+                      noc) -> dict:
+    """One flit per firing, forking along the XY tree of its destinations.
+    Firing ``f``, in priority order after its injection cycle, is made at
+    step ``f_t[f]`` and sends along tree ``s = f_tree[f]``: from core
+    ``tree_core[s]`` to each of ``tree_dest[tree_ptr[s]:tree_ptr[s + 1]]``
+    (distinct, none of them its own)."""
     cap = int(noc["link_capacity"])
     ncores = w * h
     nl = 2 * (w - 1) * h + 2 * w * (h - 1)
-    # Packets: one per distinct (firing, destination core); a firing is
-    # (t, src neuron), numbered in ascending (t, src) order.
-    u, first = np.unique((t * n + src) * ncores + dc, return_index=True)
-    psrc, pdst = sc[first], u % ncores
-    fids, pf = np.unique(u // ncores, return_inverse=True)
-    f_t = fids // n
-    f_core = np.empty(fids.shape[0], dtype=np.int64)
-    f_core[pf] = psrc
-    inject = _inject_cycles(f_t, f_core, ncores, int(noc["inject_capacity"]))
+    # Tree links: the union of the XY routes of a tree's packets, one per
+    # (tree, tail core, head core), in that order.
+    size = np.diff(tree_ptr)
+    p_tree = np.repeat(np.arange(size.shape[0]), size)
+    psrc, pdst = tree_core[p_tree], tree_dest
     hops = np.abs(psrc % w - pdst % w) + np.abs(psrc // w - pdst // w)
-    # Tree links: the union of the XY routes of a firing's packets, one
-    # entity per (firing, tail core, head core).
-    hop_pkt = np.repeat(np.arange(u.shape[0]), hops)
+    hop_pkt = np.repeat(np.arange(pdst.shape[0]), hops)
     step = np.arange(hop_pkt.shape[0]) - np.repeat(
         np.concatenate([[0], np.cumsum(hops)[:-1]]), hops)
     tail, head = _route_hop(psrc[hop_pkt], pdst[hop_pkt], step, w)
-    ent = np.unique((pf[hop_pkt] * ncores + tail) * ncores + head)
-    e_f = ent // (ncores * ncores)
-    e_tail = (ent // ncores) % ncores
-    e_link = link_id(e_tail, ent % ncores, w, h)
-    # An XY tree enters a core at most once: (firing, head) names an entity.
-    enter = e_f * ncores + ent % ncores
+    links = np.unique((p_tree[hop_pkt] * ncores + tail) * ncores + head)
+    l_tree = links // (ncores * ncores)
+    l_tail = (links // ncores) % ncores
+    l_link = link_id(l_tail, links % ncores, w, h)
+    l_ptr = np.searchsorted(l_tree, np.arange(size.shape[0] + 1))
+    # An XY tree enters a core at most once: (tree, head) names a link.
+    enter = l_tree * ncores + links % ncores
     eorder = np.argsort(enter)
     enter_sorted = enter[eorder]
-    q = e_f * ncores + e_tail
-    pos = np.minimum(np.searchsorted(enter_sorted, q), ent.shape[0] - 1)
-    parent = np.where((enter_sorted[pos] == q) & (e_tail != f_core[e_f]),
-                      eorder[pos], -1)
+    q = l_tree * ncores + l_tail
+    pos = np.minimum(np.searchsorted(enter_sorted, q), links.shape[0] - 1)
+    l_parent = np.where((enter_sorted[pos] == q)
+                        & (l_tail != tree_core[l_tree]), eorder[pos], -1)
+    p_into = eorder[np.searchsorted(enter_sorted, p_tree * ncores + pdst)]
+    # Each firing runs the links of its tree: entity ``off[f] + l`` is
+    # firing f's flit on tree link l.
+    f_lo, f_hi = l_ptr[f_tree], l_ptr[f_tree + 1]
+    base = np.concatenate([[0], np.cumsum(f_hi - f_lo)])
+    off = base[:-1] - f_lo
+    e_f = np.repeat(np.arange(f_t.shape[0]), f_hi - f_lo)
+    e_l = _ranges(f_lo, f_hi)
+    ne = e_l.shape[0]
+    parent = np.where(l_parent[e_l] >= 0, off[e_f] + l_parent[e_l], -1)
     kids = np.argsort(parent, kind="stable")
-    kids_parent = parent[kids]
-    avail = np.where(parent < 0, inject[e_f], _INF)
-    grant = np.full(ent.shape[0], -1, dtype=np.int64)
-    congestion = 0
+    kid_ptr = np.concatenate([[0], np.cumsum(np.bincount(parent + 1,
+                                                         minlength=ne + 1))])
+    inject = _inject_cycles(f_t, tree_core[f_tree], ncores,
+                            int(noc["inject_capacity"]))
     # Arbitration priority: earlier injection first, then firing order.
-    pending = np.lexsort((e_f, inject[e_f]))
-    cycle = 0
-    while pending.shape[0]:
-        req = pending[avail[pending] <= cycle]
-        if req.shape[0]:
-            go = _grants(f_t[e_f[req]] * nl + e_link[req], cap)
-            congestion += int(req.shape[0] - go.sum())
-            won = req[go]
-            grant[won] = cycle
-            # Children request from the next cycle.
-            avail[kids[_ranges(np.searchsorted(kids_parent, won),
-                               np.searchsorted(kids_parent, won, "right"))]] = cycle + 1
-            pending = pending[grant[pending] < 0]
+    order = np.argsort(inject, kind="stable")
+    by_prio = _ranges(base[order], base[order + 1])
+    prio = np.empty(ne, dtype=np.int64)
+    prio[by_prio] = np.arange(ne)
+    # A request's key orders by its tag (step, link), then by priority:
+    # the tag in the high bits, the priority in the low ``shift``.
+    shift = max(ne - 1, 1).bit_length()
+    key = ((f_t[e_f] * nl + l_link[e_l]) << shift) | prio
+    # Roots request from their injection cycle, children from the cycle
+    # after their parent's grant; a request waits until it is granted.
+    roots = np.flatnonzero(parent < 0)
+    roots = roots[np.argsort(inject[e_f[roots]], kind="stable")]
+    root_at = inject[e_f[roots]]
+    grant = np.full(ne, -1, dtype=np.int64)
+    congestion = 0
+    waiting = born = np.empty(0, dtype=np.int64)  # sorted keys; entities
+    cycle = next_root = 0
+    while True:
+        hi = int(np.searchsorted(root_at, cycle, "right"))
+        new = np.sort(key[np.concatenate([roots[next_root:hi], born])])
+        next_root = hi
+        if not (waiting.shape[0] or new.shape[0]):
+            if next_root == roots.shape[0]:
+                break
+            cycle = int(root_at[next_root])  # nothing requests before
+            continue
+        req = np.insert(waiting, np.searchsorted(waiting, new), new)
+        # The first ``cap`` requests of each tag are granted.
+        tag = req >> shift
+        go = np.ones(req.shape[0], dtype=bool)
+        go[cap:] = tag[cap:] != tag[:-cap]
+        won, waiting = by_prio[req[go] & ((1 << shift) - 1)], req[~go]
+        congestion += int(waiting.shape[0])
+        grant[won] = cycle
+        born = kids[_ranges(kid_ptr[won + 1], kid_ptr[won + 2])]
         cycle += 1
     # A packet arrives the cycle after the link into its core is granted.
-    into = eorder[np.searchsorted(enter_sorted, pf * ncores + pdst)]
-    return {"latency": grant[into] + 1, "packet_t": f_t[pf], "hops": hops,
-            "per_link_hops": np.bincount(e_link, minlength=nl),
+    pk_f = np.repeat(np.arange(f_t.shape[0]), size[f_tree])
+    pk = _ranges(tree_ptr[f_tree], tree_ptr[f_tree + 1])
+    return {"latency": grant[off[pk_f] + p_into[pk]] + 1,
+            "packet_t": f_t[pk_f], "hops": hops[pk],
+            "per_link_hops": np.bincount(l_link[e_l], minlength=nl),
             "congestion_count": congestion}
 
 

@@ -32,6 +32,10 @@ The last line of standard output is the result as JSON; the last lines of
 standard error are the numbers compared, each beside its limit.  Without a
 TPU, or with fewer chips than the cell asks for, the run prints no result
 and exits non-zero.
+
+A run that is still going when its budget (`budget_s`) has passed since
+the process started prints every thread's stack and, last on standard
+error, a line that says so, and exits with ``OVER_BUDGET`` and no result.
 """
 from __future__ import annotations
 
@@ -41,12 +45,14 @@ _T0 = time.perf_counter()
 
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
+import faulthandler  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
+import threading  # noqa: E402
 from pathlib import Path  # noqa: E402
 
 import check  # noqa: E402
@@ -62,6 +68,11 @@ LAYERS = ("profile", "partition", "mapping", "evaluate", "remap")
 # Window jobs under the profiler, from the first: its trace buffers hold
 # about 8 of the edge cell's jobs and drop the rest.
 TRACE_JOBS = 5
+# A check allows each run ``run_seconds`` + 60 s, and each cell 2 x 90 s more
+# to compile, which its first run takes.
+RUN_SLACK_S = 60
+COMPILE_S = 180
+OVER_BUDGET = 4
 
 
 class NoChip(RuntimeError):
@@ -368,6 +379,41 @@ def run_cell(config: dict, traffic: dict, seed: int, seconds: float,
     return result
 
 
+def budget_s(bench: dict, seconds: float) -> float:
+    """Seconds a run may last from the start of its process: the cell's
+    compile allowance, the window (``run_seconds``, or ``--seconds`` where
+    that is longer) and 60 s more."""
+    return COMPILE_S + max(float(bench["run_seconds"]), seconds) + RUN_SLACK_S
+
+
+def arm_budget(budget: float):
+    """Stop the process once ``budget`` seconds have passed since it
+    started: every thread's stack, then a line that says why, on standard
+    error, and the exit code ``OVER_BUDGET``.  Where the interpreter's lock
+    is held so long that this cannot run, faulthandler's own watchdog dumps
+    the stacks and exits 30 s later.  Returns the function that disarms
+    both."""
+    left = max(budget - (time.perf_counter() - _T0), 0.0)
+
+    def stop():
+        faulthandler.dump_traceback(file=sys.stderr, all_threads=True)
+        print(f"bench/run.py: stopped {time.perf_counter() - _T0:.1f} s after "
+              f"the start, over the run's budget of {budget:.0f} s",
+              file=sys.stderr, flush=True)
+        os._exit(OVER_BUDGET)
+
+    timer = threading.Timer(left, stop)
+    timer.daemon = True
+    timer.start()
+    faulthandler.dump_traceback_later(left + 30, exit=True)
+
+    def disarm():
+        timer.cancel()
+        faulthandler.cancel_dump_traceback_later()
+
+    return disarm
+
+
 def _xplane() -> Path:
     found = sorted(TRACE_DIR.glob("plugins/profile/*/*.xplane.pb"))
     if not found:
@@ -383,6 +429,7 @@ def main() -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args()
     bench, cell, config, traffic = load_cell(args.workload)
+    disarm = arm_budget(budget_s(bench, args.seconds))
     os.environ["JAX_COMPILATION_CACHE_DIR"] = str(CACHE_DIR)
     seed = int(traffic["job_seed"])
     print(f"[run] workload={args.workload} seed={args.seed} job_seed={seed}",
@@ -396,6 +443,8 @@ def main() -> int:
     except NoChip as e:
         print(f"bench/run.py: {e}", file=sys.stderr)
         return 3
+    finally:
+        disarm()
     print(json.dumps(result), flush=True)
     for name, c in result["checks"].items():
         print(f"check {name} {c['value']} limit {c['limit']}", file=sys.stderr)

@@ -22,6 +22,13 @@ CASES = [
 ]
 
 
+# On the tiny random network under multicast: (fault, a number it must fail).
+RANDOM_CASES = [
+    ("half_batch", "noc_num_noc_spikes"),
+    ("alter_replay", "noc_avg_latency"),
+]
+
+
 # Under the tiny core schedule: (control or fault, a number it must fail).
 FAULT_CASES = [
     ("bf16_emul", "profile_fires"),
@@ -43,6 +50,15 @@ FAULT_CASES = [
 def test_control_is_not_correct(name, cast, number):
     with control.CONTROLS[name]():
         res = tiny.run(cast)
+    assert not res["correct"]
+    assert number in control.failing(res)
+
+
+@pytest.mark.parametrize("name,number", RANDOM_CASES,
+                         ids=[c[0] for c in RANDOM_CASES])
+def test_control_is_not_correct_on_random_layers(name, number):
+    with control.CONTROLS[name]():
+        res = tiny.run("multicast", network=tiny.RANDOM)
     assert not res["correct"]
     assert number in control.failing(res)
 

@@ -72,7 +72,24 @@ def test_a_link_between_cores_that_are_not_neighbours_is_refused():
         run.toolchain_kwargs(tc)
 
 
-def test_networks_match_make_snn():
+# SNEAP Table 1's MLP_2048 and Random_6212 as the program's `make_snn`
+# lists them.
+MLP_2048 = {"name": "mlp_2048", "layers": [1024, 1024],
+            "connections": [{"kind": "full"}], "gain": 2.0,
+            "weight_quantum": 2.0 ** -20, "input_rate": 0.06,
+            "input_amp": 1.5, "table1_transmissions": 15_905_792}
+RANDOM_6212 = {"name": "random_6212", "layers": [2071, 2070, 2071],
+               "connections": [{"kind": "random", "p": 0.10},
+                               {"kind": "random", "p": 0.10}],
+               "gain": 2.5, "weight_quantum": 2.0 ** -20, "input_rate": 0.12,
+               "input_amp": 1.5, "table1_transmissions": 51_756_245,
+               "seed": 0}
+NETWORKS = [json.loads((ROOT / c["file"]).read_text())["network"]
+            for c in SPEC["configs"]] + [MLP_2048, RANDOM_6212]
+
+
+@pytest.mark.parametrize("spec", NETWORKS, ids=lambda s: s["name"])
+def test_networks_match_make_snn(spec):
     """The benchmark's own network generator makes the Table 1 networks the
     program's `make_snn` makes, synapse for synapse and weight for weight."""
     import numpy as np
@@ -80,14 +97,21 @@ def test_networks_match_make_snn():
     import network
     from repro.snn import make_snn
 
-    for entry in SPEC["configs"]:
-        spec = json.loads((ROOT / entry["file"]).read_text())["network"]
-        net = network.build_network(spec)
-        topo = make_snn(spec["name"])
-        assert np.array_equal(net.weights, topo.weights)
-        n = net.num_neurons
-        assert np.array_equal(
-            np.sort(net.syn_src * n + net.syn_dst),
-            np.sort(topo.syn_src.astype(np.int64) * n + topo.syn_dst))
-        assert net.target_spikes == topo.target_spikes
-        assert net.input_rate == topo.input_rate
+    net = network.build_network(spec)
+    topo = make_snn(spec["name"])
+    assert np.array_equal(net.weights, topo.weights)
+    n = net.num_neurons
+    assert np.array_equal(
+        np.sort(net.syn_src * n + net.syn_dst),
+        np.sort(topo.syn_src.astype(np.int64) * n + topo.syn_dst))
+    assert net.target_spikes == topo.target_spikes
+    assert net.input_rate == topo.input_rate
+
+
+def test_an_unknown_connectivity_names_its_missing_file():
+    import network
+    import tiny
+
+    spec = dict(tiny.NETWORK, connections=[{"kind": "ring"}])
+    with pytest.raises(ValueError, match=r"no file bench/networks/ring\.py"):
+        network.build_network(spec)

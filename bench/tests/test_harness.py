@@ -1,6 +1,8 @@
 """A whole run of the harness at the tiny size on the CPU: set-up, window,
 the comparison, and every metric reader."""
 import json
+import subprocess
+import sys
 
 import pytest
 
@@ -11,9 +13,12 @@ from conftest import BENCH
 SPEC = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
 
 
-@pytest.mark.parametrize("cast", ["unicast", "multicast"])
-def test_untraced_run_is_correct(cast):
-    res = tiny.run(cast)
+@pytest.mark.parametrize("cast,net", [("unicast", tiny.NETWORK),
+                                     ("multicast", tiny.NETWORK),
+                                     ("multicast", tiny.RANDOM)],
+                         ids=["unicast", "multicast", "multicast-random"])
+def test_untraced_run_is_correct(cast, net):
+    res = tiny.run(cast, network=net)
     assert res["correct"], {k: c for k, c in res["checks"].items() if c["value"]}
     assert res["attempted"] >= 1 and res["failed"] == 0
     assert set(res["metrics"]) == {m["name"] for m in SPEC["end_to_end"]}
@@ -79,3 +84,41 @@ def test_refuses_to_run_off_the_chip():
     with pytest.raises(run.NoChip):
         run.run_cell(tiny.config(), tiny.traffic(), 0, 0.0, False, [],
                      log=lambda _: None)
+
+
+# A run whose job sleeps past a budget of 2 s.
+OVER_BUDGET = f"""
+import sys, time
+sys.path[:0] = [{str(BENCH)!r}, {str(BENCH.parent / "src")!r}]
+import run
+
+def job_that_sleeps(*args, **kwargs):
+    time.sleep(120)
+
+run.budget_s = lambda bench, seconds: 2.0
+run.run_cell = job_that_sleeps
+sys.argv = ["run.py", "--workload", {SPEC["workloads"][0]["name"]!r},
+            "--seed", "1", "--seconds", "1"]
+sys.exit(run.main())
+"""
+
+
+def test_a_run_over_its_budget_stops():
+    import run
+
+    proc = subprocess.run([sys.executable, "-c", OVER_BUDGET],
+                          capture_output=True, text=True, timeout=60)
+    assert proc.returncode == run.OVER_BUDGET
+    assert "Thread" in proc.stderr and "in job_that_sleeps" in proc.stderr
+    last = proc.stderr.strip().splitlines()[-1]
+    assert last.startswith("bench/run.py: stopped") and "budget of 2 s" in last
+    assert "{" not in proc.stdout
+
+
+def test_the_budget_is_the_checks_allowance():
+    """Compile allowance, window and 60 s; a longer window than
+    ``run_seconds`` widens it."""
+    import run
+
+    assert run.budget_s(SPEC, 0.0) == 180 + SPEC["run_seconds"] + 60
+    assert run.budget_s(SPEC, 500.0) == 180 + 500 + 60

@@ -97,6 +97,50 @@ def test_objectives_and_avg_hop_match_the_program():
                                  cast) == want
 
 
+def _assert_same(got: dict, want: dict) -> None:
+    for field, value in want.items():
+        if isinstance(value, np.ndarray):
+            assert np.array_equal(got[field], value), field
+        else:
+            assert got[field] == value, field
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_random_job_replays_as_the_program(seed):
+    """A random-connectivity job (the tiny random network drawn from
+    ``seed``, a random partition and placement on a 3x3 mesh, one packet
+    per link per cycle): the reference's replay from the firings, the
+    check's path, equals its replay of the trace and the program's
+    multicast replay in every field; its hop count from the fire counts
+    equals the one from the trace."""
+    from repro.nocsim import simulate_noc
+    from repro.nocsim.energy import EnergyModel
+
+    cfg, traffic = tiny.config(), tiny.traffic()
+    net = network.build_network(dict(tiny.RANDOM, seed=seed))
+    ref = reference.profile(net, network.input_drive(
+        net, traffic["num_steps"], seed), cfg["lif"])
+    n, w, h = net.num_neurons, 3, 3
+    rng = np.random.default_rng(seed)
+    part = rng.integers(0, 6, n)
+    placement = rng.permutation(w * h)[:6]
+    noc = dict(NOC, link_capacity=1)
+    want = reference.replay_firings(net, ref["firings"], part, placement, w,
+                                    h, noc)
+    assert want["congestion_count"] > 0  # the queues are exercised
+    _assert_same(reference.replay(ref["trace"], n, part, placement, w, h, noc,
+                                  "multicast"), want)
+    t, s, d = reference.unpack(ref["trace"], n)
+    _assert_same(dataclasses.asdict(simulate_noc(
+        t.astype(np.int32), s.astype(np.int32), d.astype(np.int32), part,
+        placement, w, h, link_capacity=1,
+        inject_capacity=NOC["inject_capacity"], cast="multicast",
+        engine="batched", energy=EnergyModel())), want)
+    assert reference.multicast_avg_hop(net, ref["fire_counts"], part,
+                                       placement, w) == \
+        reference.avg_hop(ref["trace"], n, part, placement, w, "multicast")
+
+
 def test_violations():
     assert reference.partition_violations(np.array([0, 1, 1]), 2, 2, 4) == 0
     assert reference.partition_violations(np.array([0, 1, 1]), 2, 1, 4) == 1

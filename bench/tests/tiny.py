@@ -1,4 +1,5 @@
-"""A cell small enough for the CPU: Smooth_320 on a 3x3 mesh."""
+"""A cell small enough for the CPU: Smooth_320 on a 3x3 mesh, or two
+random layers of the same size."""
 import copy
 import json
 from pathlib import Path
@@ -10,6 +11,12 @@ NETWORK = {"name": "smooth_320", "layers": [160, 160],
            "weight_quantum": 2.0 ** -20, "input_rate": 0.14, "input_amp": 1.5,
            "table1_transmissions": 60000}
 
+# Random_6212's connectivity at the size of Smooth_320.
+RANDOM = {"name": "random_320", "layers": [160, 160],
+          "connections": [{"kind": "random", "p": 0.1}], "gain": 2.5,
+          "weight_quantum": 2.0 ** -20, "input_rate": 0.12, "input_amp": 1.5,
+          "table1_transmissions": 40000, "seed": 3}
+
 
 # Fault schedules on the tiny job's 3x3 mesh, where its six parts sit on
 # cores 4, 7, 2, 5, 8 and 1: links that carry traffic, and two busy cores.
@@ -20,10 +27,11 @@ CORES = [{"t": 50, "kind": "link", "from": 1, "to": 2},
          {"t": 200, "kind": "core", "ids": [8]}]
 
 
-def config(cast: str = "unicast", faults: list | None = None) -> dict:
+def config(cast: str = "unicast", faults: list | None = None,
+           network: dict = NETWORK) -> dict:
     with open(BENCH / "configs" / "edge_5120_unicast_5x5.json") as f:
         cfg = json.load(f)
-    cfg["network"] = copy.deepcopy(NETWORK)
+    cfg["network"] = copy.deepcopy(network)
     cfg["toolchain"].update(
         mesh_w=3, mesh_h=3, capacity=64,
         objective="cut" if cast == "unicast" else "volume", cast=cast,
@@ -49,12 +57,13 @@ def traffic() -> dict:
     return t
 
 
-def run(cast="unicast", trace=False, seed=2**33 + 5, faults=None):
+def run(cast="unicast", trace=False, seed=2**33 + 5, faults=None,
+        network=NETWORK):
     """One run of the harness at the tiny size, off the chip."""
     import run as harness
 
     bench = harness.load_json(harness.ROOT / "BENCHMARK.json")
     metrics = bench["per_layer" if trace else "end_to_end"]
-    return harness.run_cell(config(cast, faults), traffic(), seed, 0.0, trace,
-                            metrics, require_tpu=False, compile_cache=False,
-                            log=lambda _: None)
+    return harness.run_cell(config(cast, faults, network), traffic(), seed,
+                            0.0, trace, metrics, require_tpu=False,
+                            compile_cache=False, log=lambda _: None)
