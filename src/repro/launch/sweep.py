@@ -17,8 +17,10 @@ corresponding single run, while the driver wins wall-clock three ways:
     (`repro.core.mapping_jax.sa_search_jax_batch`), advancing every
     config's whole chain population in lock-step;
   * **jit-cache reuse** — ``stepper="jax"`` replays pad packet arrays to
-    power-of-two shapes (`repro.nocsim.replay_jax`), so the grid's
-    evaluations bucket into a handful of compiled programs.
+    power-of-two shapes and shrink them through power-of-two buckets
+    (`repro.nocsim.replay_jax`), so the grid's evaluations share a
+    handful of compiled programs: one per bucket of each padded width
+    (three at 2^21 packets), the narrower buckets shared across widths.
 
 The grid can also carry engine-threshold overrides
 (``knobs={"_KERNEL_MAX_N": ...}``, ``score_backend``, ``stepper``,

@@ -115,10 +115,15 @@ def hot_link_trace(seed):
 @pytest.mark.parametrize("link_capacity", [1, 2, 3])
 @pytest.mark.parametrize("seed", [1, 7])
 @pytest.mark.parametrize("trace", ["random", "hot_link"])
-def test_jax_stepper_matches_ref(trace, seed, link_capacity):
+@pytest.mark.parametrize("floor", ["default", "lowered"])
+def test_jax_stepper_matches_ref(floor, trace, seed, link_capacity,
+                                 monkeypatch):
     pytest.importorskip("jax")
     from repro import telemetry
+    from repro.nocsim import replay_jax
 
+    if floor == "lowered":  # several buckets, each compacting its lanes
+        monkeypatch.setattr(replay_jax, "_MIN_LANES", 1 << 4)
     if trace == "random":
         args = random_spike_trace(seed=seed, n_spikes=800, timesteps=6)
     else:
@@ -130,9 +135,36 @@ def test_jax_stepper_matches_ref(trace, seed, link_capacity):
                            engine="batched", stepper="jax")
     assert stats_equal(ref, new) == []
     (stepper,) = root.find("stepper")
-    # Some lanes are padding: the stepped count is not a power of two.
-    assert 0 < root.counters["stepped"] < stepper.counters["lanes"]
+    if floor == "default":
+        # Some lanes are padding: the stepped count is not a power of two.
+        assert 0 < root.counters["stepped"] < stepper.counters["lanes"]
+        assert stepper.counters["buckets"] == 1
+    else:
+        assert stepper.counters["buckets"] > 1
     assert new.congestion_count > 0
+
+
+def test_jax_stepper_drains_in_first_bucket(monkeypatch):
+    """Two packets from core 2 contend for its west link: the two-hop one
+    to core 0 wins (it is first in record order), the one-hop one to core
+    1 follows a cycle later, and both arrive at cycle 2.  The first bucket
+    steps until then; the second steps no cycle."""
+    pytest.importorskip("jax")
+    from repro import telemetry
+    from repro.nocsim import replay_jax
+
+    monkeypatch.setattr(replay_jax, "_MIN_LANES", 1)
+    args = (np.array([0, 0]), np.array([2, 2]), np.array([0, 1]),
+            np.arange(3), np.arange(9))
+    ref = simulate_noc(*args, 3, 3, link_capacity=1, engine="ref")
+    with telemetry.span("t_first_bucket") as root:
+        new = simulate_noc(*args, 3, 3, link_capacity=1, stepper="jax")
+    assert stats_equal(ref, new) == []
+    assert (new.congestion_count, new.max_latency) == (1, 2)
+    (stepper,) = root.find("stepper")
+    assert root.counters["stepped"] == 2
+    assert (stepper.counters["buckets"], stepper.counters["lanes"],
+            stepper.counters["cycles"]) == (1, 2, 2)
 
 
 def test_jax_stepper_has_no_gather_or_scatter():
@@ -141,13 +173,19 @@ def test_jax_stepper_has_no_gather_or_scatter():
     jax = pytest.importorskip("jax")
     from repro.nocsim.replay_jax import _run
 
-    n, w = 1000, 3
+    n, w = 1024, 3
     ints = [jax.ShapeDtypeStruct((n,), np.int32) for _ in range(4)]
-    text = _run.lower(*ints, jax.ShapeDtypeStruct((n,), np.bool_), w=w, h=w,
-                      nl=link_count(w, w), capacity=2,
-                      max_cycles=1000).as_text()
-    assert "stablehlo.sort" in text
-    assert "gather" not in text and "scatter" not in text
+    lanes = [jax.ShapeDtypeStruct((n,), np.bool_)] + [
+        jax.ShapeDtypeStruct((n,), np.int32) for _ in range(2)]
+    scalars = [jax.ShapeDtypeStruct((), t)
+               for t in (np.int32, np.bool_, np.int32)]
+    # A shrinking bucket, with its compaction sort, and the last bucket.
+    for out in (n // 2, 0):
+        text = _run.lower(*ints, *lanes, *scalars, w=w, h=w,
+                          nl=link_count(w, w), capacity=2, max_cycles=1000,
+                          out=out).as_text()
+        assert "stablehlo.sort" in text
+        assert "gather" not in text and "scatter" not in text
 
 
 def test_screen_backends_do_not_change_results():
@@ -166,12 +204,21 @@ def test_screen_backends_do_not_change_results():
     assert stats_equal(mc, mc2) == []
 
 
-def test_undrainable_window_raises():
+def test_undrainable_window_raises(monkeypatch):
     t, src, dst, part, placement = random_spike_trace(seed=0, n_spikes=200)
     for engine in ("ref", "batched"):
         with pytest.raises(RuntimeError):
             simulate_noc(t, src, dst, part, placement, 3, 3, link_capacity=0,
                          engine=engine, max_cycles_per_window=50)
+    # The JAX stepper hits max_cycles in a bucket that would shrink: the
+    # first of several, with more than half its lanes live.
+    pytest.importorskip("jax")
+    from repro.nocsim import replay_jax
+
+    monkeypatch.setattr(replay_jax, "_MIN_LANES", 1 << 4)
+    with pytest.raises(RuntimeError):
+        simulate_noc(t, src, dst, part, placement, 3, 3, link_capacity=0,
+                     stepper="jax", max_cycles_per_window=50)
 
 
 # ------------------------------------- (b) unbounded -> analytic degeneracy

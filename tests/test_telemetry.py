@@ -196,3 +196,49 @@ def test_stepper_counters_on_a_congested_trace(monkeypatch):
     assert (outer_again["stepped"], outer_again["noc_records"]) == \
         (outer["stepped"], outer["noc_records"])
     assert again["compiles"] == 0
+
+
+def test_stepper_counters_across_buckets(monkeypatch):
+    """With the ladder's floor lowered the loop runs several buckets; its
+    lane-cycles are recomputed here from the numpy stepper's latencies."""
+    from repro.nocsim import replay
+
+    floor = 1 << 4
+    monkeypatch.setattr(replay_jax, "_MIN_LANES", floor)
+    stats, c, seen, outer = _stepped_run(monkeypatch)
+    numpy_lat = {}
+    real = replay._joint_stepper
+
+    def spy(*args):
+        numpy_lat["lat"], cong = real(*args)
+        return numpy_lat["lat"], cong
+
+    monkeypatch.setattr(replay, "_joint_stepper", spy)
+    t, src, dst, part, placement = random_spike_trace(
+        seed=1, n_spikes=800, timesteps=6)
+    simulate_noc(t, src, dst, part, placement, 3, 3, link_capacity=1,
+                 stepper="numpy")
+    lat = numpy_lat["lat"]
+    np.testing.assert_array_equal(lat, seen["lat"])
+    # A bucket of m lanes steps cycle c while more than the next bucket's
+    # width are live, the floor's until none is; live at cycle c: the
+    # packets with lat > c.
+    n = outer["stepped"]
+    widths = replay_jax._ladder(1 << (n - 1).bit_length())
+    assert widths[-1] == floor
+    lane_cycles, b, stepped = 0, 0, set()
+    for cycle in range(int(lat.max())):
+        live = int(np.count_nonzero(lat > cycle))
+        while b + 1 < len(widths) and live <= widths[b + 1]:
+            b += 1
+        lane_cycles += widths[b]
+        stepped.add(b)
+    assert c["cycles"] == int(lat.max())
+    assert round(c["cycles"] * c["lanes"]) == lane_cycles
+    assert c["buckets"] == len(stepped) > 1
+    assert c["grants"] == seen["hops"] > 0
+    assert c["blocked"] == stats.congestion_count > 0
+    assert c["grants"] + c["blocked"] <= c["cycles"] * c["lanes"]
+    _, again, _, _ = _stepped_run(monkeypatch)
+    work = ("lanes", "buckets", "cycles", "grants", "blocked")
+    assert {k: again[k] for k in work} == {k: c[k] for k in work}

@@ -137,11 +137,16 @@ def test_replay_stepper_compiles(one_chip):
     # TPU compiler ~15 s at 2^14 packets and minutes from 2^16 on.
     n, w = 1 << 12, 5
     ints = [spec((n,), jnp.int32, one_chip) for _ in range(4)]
-    c = _run.lower(*ints, spec((n,), np.bool_, one_chip), w=w, h=w,
-                   nl=link_count(w, w), capacity=4,
-                   max_cycles=100_000).compile()
-    # Lanes are reordered by sorts alone, in the chip's program too: no
-    # gather or scatter runs through the sort's permutation.
-    text = c.as_text()
-    assert re.search(r"\bsort\(", text)
-    assert not re.search(r"\b(gather|scatter)\(", text)
+    lanes = [spec((n,), np.bool_, one_chip),
+             spec((n,), jnp.int32, one_chip), spec((n,), jnp.int32, one_chip)]
+    scalars = [spec((), t, one_chip) for t in (jnp.int32, np.bool_, jnp.int32)]
+    # A shrinking bucket, with its compaction sort, and the last bucket.
+    for out in (n // 2, 0):
+        c = _run.lower(*ints, *lanes, *scalars, w=w, h=w,
+                       nl=link_count(w, w), capacity=4, max_cycles=100_000,
+                       out=out).compile()
+        # Lanes are reordered by sorts alone, in the chip's program too: no
+        # gather or scatter runs through the sort's permutation.
+        text = c.as_text()
+        assert re.search(r"\bsort\(", text)
+        assert not re.search(r"\b(gather|scatter)\(", text)
