@@ -14,10 +14,10 @@ it one sequential call at a time wastes everything the configs share.
   * `stepper="jax"` replays share pow2-padded compiled programs.
 
 Rows are bitwise-identical to what sequential `run_toolchain` calls
-would produce (the `benchmarks/bench_sweep.py` parity gate proves it),
-so the sweep is a pure wall-clock win. This example sweeps two meshes x
-two partition objectives x mappers x seeds and prints the Pareto front
-over (energy, latency, toolchain seconds).
+would produce (`tests/test_sweep.py::test_sweep_rows_match_sequential_bitwise`
+holds them to it), so only the wall clock differs. This example sweeps
+two meshes x two partition objectives x mappers x seeds and prints the
+Pareto front over (energy, latency, toolchain seconds).
 """
 import argparse
 import sys

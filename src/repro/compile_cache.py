@@ -1,6 +1,6 @@
 """JAX's persistent compilation cache for the toolchain's entry points.
 
-Entry points (``chip_smoke.py``, ``benchmarks/run.py``,
+Entry points (``chip_smoke.py``, ``bench/run.py``,
 ``examples/quickstart.py``) call `enable_compile_cache` before their first
 compile; importing this module changes nothing.
 """

@@ -3,7 +3,8 @@
 * SpiNeMap [Balaji et al., TVLSI'19]: SpiNeCluster — a greedy
   Kernighan–Lin partitioner that works directly on the *full* graph with
   per-partition priority queues over *all* vertices (no multilevel
-  coarsening — this is why SNEAP wins 890x on partitioning time), plus
+  coarsening — why the paper reports SNEAP 890x faster at partitioning,
+  Fig. 4), plus
   SpiNePlacer — a PSO placement search.
 * SCO [Lee et al., TACO'19]: sequential mapping that packs neurons into
   cores in index order to minimize core usage, with no communication

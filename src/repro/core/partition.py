@@ -13,9 +13,9 @@ Two interchangeable engines drive the coarsen/refine hot path:
   (`coarsen.heavy_edge_matching_vec` + `refine_vec.refine_level_vec`):
   round-based mutual-proposal matching and batched conflict-free
   positive-gain refinement, all as whole-array numpy passes (with an
-  optional `kernels.gain_eval` Pallas path on TPU).  Within a few percent
-  of the scalar cut at a tiny fraction of the time — the engine to use
-  for ≳10^4-neuron graphs.
+  optional `kernels.gain_eval` Pallas path on TPU).  The engine to use
+  for ≳10^4-neuron graphs; the tests hold its cut within 10% of the
+  scalar engine's.
 
 Two objectives drive both engines (selected by ``objective``):
 
@@ -28,7 +28,7 @@ Two objectives drive both engines (selected by ``objective``):
   measures.  Requires ``graph.hyper`` (set by `snn.simulate.profile_snn`).
 
 Both produce `validate_partition`-clean results and share every other
-knob; `benchmarks/bench_partition.py` tracks their cut/time trade-off.
+knob.
 """
 from __future__ import annotations
 

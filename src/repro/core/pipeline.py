@@ -451,57 +451,39 @@ def run_toolchain(
     work across configs, batches same-shape ``mapper="sa_jax"`` searches
     into one vmapped device program, and emits a per-workload Pareto
     report over (energy, latency, toolchain seconds); each sweep row is
-    bitwise the stats of the corresponding single ``run_toolchain`` call
-    (`results/bench_sweep.csv` records the wall-clock advantage).
+    bitwise the stats of the corresponding single ``run_toolchain`` call.
 
-    Performance of the evaluation phase: ``noc_mode="queued"`` runs the
-    batched two-tier replay (`repro.nocsim.replay`) — contention-free
-    windows are scored analytically from whole-window link loads and the
-    static XY schedule, and only truly contending packets are
-    cycle-stepped, jointly across windows.  On bursty traces this is
-    10-20x the scalar reference engine (``noc_kwargs={"engine": "ref"}``),
-    which remains available for parity diffs; on saturated traces where
-    every window queues heavily, a pigeonhole detector routes provably
-    congested windows straight to the stepper (skipping the schedule
-    screen) and the engines run neck and neck (~1.2x).
-    Under ``cast="multicast"`` the replay simulates true tree-fork flits
-    (one flit per firing, forking at branch routers), which is both
-    faster than the old per-replica simulation and reports strictly
-    tighter latency/congestion.  ``ToolchainResult.summary()`` reports
-    ``evaluate_s`` next to ``partition_s``/``mapping_s`` so the phase
-    balance is visible per run.
+    Evaluation phase: ``noc_mode="queued"`` runs the batched two-tier
+    replay (`repro.nocsim.replay`) — contention-free windows are scored
+    analytically from whole-window link loads and the static XY
+    schedule, and only truly contending packets are cycle-stepped,
+    jointly across windows; a pigeonhole detector routes provably
+    congested windows straight to the stepper.  The scalar reference
+    engine (``noc_kwargs={"engine": "ref"}``) remains available for
+    parity diffs.  Under ``cast="multicast"`` the replay simulates
+    tree-fork flits (one flit per firing, forking at branch routers).
+    ``ToolchainResult.summary()`` reports ``evaluate_s`` next to
+    ``partition_s``/``mapping_s`` so the phase balance is visible per run.
 
-    Performance of the mapping phase: ``mapper_kwargs={"impl": "vec"}``
-    runs the SA search's batched engine — ``batch`` candidate swaps are
-    scored per step in one vectorized delta call (optionally through the
-    `kernels/swap_delta` MXU batch via ``score_backend``) and a
-    conflict-free accepted subset is committed with an exact cost resync.
-    At 256 cores this is ~7x the scalar chain's proposals per second at
-    matched quality (``results/bench_mapping_engine.csv``); the scalar
-    chain (``impl="scalar"``, the default) remains the parity reference.
-    The tree objective's batched path scores swaps from member-level
-    span aggregates (per-hyperedge top-2 column extremes plus
-    per-(edge, column) top-2 row extremes — see
-    `repro.core.placecost.TreeHopObjective`), so a destination move
-    prices each incident edge in O(1) instead of re-measuring its
-    route geometry: ~4x the scalar chain at 256 cores and first
-    usable at 1024 cores (32x32), where the same wall-clock budget
-    buys the batched engine a few percent *better* tree cost
-    (``eqclock_delta`` in the CSV).  Every search reports both
-    ``avg_hop`` and ``tree_hop`` through the shared evaluator
-    regardless of which objective drove it.
+    Mapping phase: ``mapper_kwargs={"impl": "vec"}`` runs the SA search's
+    batched engine — ``batch`` candidate swaps are scored per step in one
+    vectorized delta call (optionally through the `kernels/swap_delta`
+    MXU batch via ``score_backend``) and a conflict-free accepted subset
+    is committed with an exact cost resync; the scalar chain
+    (``impl="scalar"``, the default) remains the parity reference.  The
+    tree objective's batched path scores swaps from member-level span
+    aggregates (see `repro.core.placecost.TreeHopObjective`), so a
+    destination move prices each incident edge in O(1) instead of
+    re-measuring its route geometry.  Every search reports both
+    ``avg_hop`` and ``tree_hop`` through the shared evaluator regardless
+    of which objective drove it.
 
-    Performance of ``objective="volume"``: with ``partition_impl="vec"``
-    the refiner keeps the Φ(e, p) member-count table and the D* degree
-    matrix incremental across move batches and walks plateaus with bounded
-    escape rounds, so volume partitioning runs at cut-path speed (often
-    faster, since hyperedge dedup shrinks coarse levels) while matching
-    the scalar FM queue's quality within a few percent.  With
-    ``partition_impl="scalar"`` the λ-gain FM queue is the paper-faithful
-    reference but pays a per-move cost proportional to the incident pin
-    count times k — expect it to be ~5-15x slower than the cut objective
-    on fan-out-heavy graphs; prefer the vec engine for graceful volume at
-    scale.
+    ``objective="volume"``: with ``partition_impl="vec"`` the refiner
+    keeps the Φ(e, p) member-count table and the D* degree matrix
+    incremental across move batches and walks plateaus with bounded
+    escape rounds.  With ``partition_impl="scalar"`` the λ-gain FM queue
+    is the paper-faithful reference and pays a per-move cost proportional
+    to the incident pin count times k.
 
     Partition phase at scale: for million-neuron SNNs pass
     ``partition_kwargs={"shards": S, "stream_levels": True}`` (vec impl
@@ -514,9 +496,6 @@ def run_toolchain(
     spills each coarsening level to an on-disk `repro.core.coarsen.
     LevelStore` and uncoarsens out-of-core with at most two levels
     resident, for identical results at bounded peak RSS.
-    ``benchmarks/bench_scale.py`` tracks both: the 1M-neuron/10M-synapse
-    run and the sharded-vs-single-host quality parity gate (<= 5%
-    comm_volume drift; measured ~0.03% at 100k neurons).
 
     Graceful degradation: ``fault_schedule`` (a `repro.runtime.faults.
     FaultSchedule`) injects core/link failures at trace-window boundaries.

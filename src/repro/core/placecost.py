@@ -109,10 +109,8 @@ class PairwiseObjective:
         self._placement: np.ndarray | None = None
         # Placement-permuted distance columns, attached-state cache:
         # _dist_p[c, j] = dist[c, placement[j]].  Lets the batch scorer use
-        # contiguous row gathers instead of broadcast fancy indexing (the
-        # difference between ~5 ms and ~0.3 ms per 512-candidate batch at
-        # 256 cores); a committed swap of positions (a, b) just swaps
-        # columns a and b.
+        # contiguous row gathers instead of broadcast fancy indexing; a
+        # committed swap of positions (a, b) just swaps columns a and b.
         self._dist_p: np.ndarray | None = None
         self._total = 0.0
 

@@ -710,7 +710,7 @@ def refine_level_vec(
             nr = remaining.shape[0]
             # Segment-any over the (pair -> candidate) map as bincounts of
             # the offending pair subset (buffered C loops; the equivalent
-            # ``np.logical_or.at`` is unbuffered and ~10x slower here).
+            # ``np.logical_or.at`` is unbuffered, so slower here).
             eidx, local = _row_edges(graph, remaining)
             u, v = remaining[local], nbr[eidx]
             excl = np.bincount(local[status[v] == 2], minlength=nr) > 0

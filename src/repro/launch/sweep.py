@@ -24,14 +24,12 @@ corresponding single run, while the driver wins wall-clock three ways:
 
 The grid can also carry engine-threshold overrides
 (``knobs={"_KERNEL_MAX_N": ...}``, ``score_backend``, ``stepper``,
-``screen``) so one sweep measures the CPU-reasoned crossover defaults on
-real hardware; `benchmarks/bench_sweep.py` records the resulting
-data-driven defaults in ``results/bench_sweep.csv``.
+``screen``), so one sweep compares engine settings on one workload.
 
 Per workload the report flags the Pareto front over
 (energy_pj, avg_latency, total_s) — minimum energy, minimum replay
 latency, minimum toolchain seconds — the three axes the SNEAP paper
-trades (418x toolchain speedup at matched mapping quality).
+trades.
 """
 from __future__ import annotations
 

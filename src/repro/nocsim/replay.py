@@ -333,9 +333,8 @@ def queued_unicast(
         # capacity x its unobstructed cycle span is congested by
         # pigeonhole — no schedule can grant that demand — so it skips
         # the screen's (window, cycle, link) sort; on fully saturated
-        # traces that empties the screen entirely (the old
-        # `saturated_unicast` 0.8x gap), while merely-bursty windows
-        # still get screened (where the pruning pays for itself).
+        # traces that empties the screen entirely, while merely-bursty
+        # windows still get screened (where the pruning pays for itself).
         uwin0 = np.unique(win[sidx])
         cwin0 = np.searchsorted(uwin0, win[sidx])
         nw0 = uwin0.shape[0]

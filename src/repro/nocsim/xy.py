@@ -298,7 +298,7 @@ def multicast_tree_sizes(
     # each rides on one plain sort of a shift-packed (segment, offset) key:
     # the first entry of a segment is its min, the last its max.  Segments
     # here average only a few entries, so sort + boundary picks beats
-    # ufunc.reduceat's per-segment dispatch by ~10x; shift packing keeps
+    # ufunc.reduceat's per-segment dispatch; shift packing keeps
     # the unpack passes at mask/shift cost (int division is the slow part).
     return (
         sizes
@@ -385,7 +385,7 @@ def _packed_span(seg: np.ndarray, off: np.ndarray, radius: int,
     ``s // scale``.  One sort of ``(seg << bits) | (off + radius)`` orders
     segments contiguously with offsets ascending inside, so each segment's
     min/max are its boundary entries.  Sorts in int32 when the packed key
-    fits — ~2x faster for the sizes the mapping engine batches.
+    fits, which is faster for the sizes the mapping engine batches.
     """
     bits = int(2 * radius - 1).bit_length()
     key = (seg << bits) | (off + radius)

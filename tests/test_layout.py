@@ -32,7 +32,7 @@ def test_layout_respects_dead_chips():
 def test_layout_improves_alltoall_traffic():
     """MoE expert-parallel all-to-all on the model axis: row-major lines
     are suboptimal (compact blocks have lower mean pairwise distance);
-    seeded-hot SA must strictly improve (examples/sneap_mesh_layout.py)."""
+    seeded-hot SA must strictly improve."""
     order, base, optimized = sneap_device_layout(
         {"data": 16, "model": 16}, {"data": 5e8, "model": 5e9},
         phys_w=16, iters=120_000, seed=0, patterns={"model": "alltoall"})

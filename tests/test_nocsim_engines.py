@@ -94,6 +94,50 @@ def test_congested_windows_actually_step():
     assert jam.avg_latency > jam.avg_hop
 
 
+def bursty_trace(n_firings, fan=1, hot=False, hot_frac=0.7, nhot=2,
+                 timesteps=1200, n_neurons=2048, cores=64, seed=0):
+    """Each firing goes to ``fan`` random targets; with ``hot``, a quarter
+    of the time steps send ``hot_frac`` of their traffic to ``nhot``
+    destination neurons, so those windows queue."""
+    r = np.random.default_rng(seed)
+    part = r.integers(0, cores, n_neurons)
+    placement = r.permutation(cores)
+    t = np.repeat(np.sort(r.integers(0, timesteps, n_firings)), fan)
+    src = np.repeat(r.integers(0, n_neurons, n_firings), fan)
+    dst = r.integers(0, n_neurons, t.shape[0])
+    if hot:
+        hot_steps = r.permutation(timesteps)[:timesteps // 4]
+        m = np.isin(t, hot_steps) & (r.random(t.shape[0]) < hot_frac)
+        dst[m] = r.integers(0, n_neurons, nhot)[r.integers(0, nhot, m.sum())]
+    return t, src, dst, part, placement
+
+
+_STATIC_FIELDS = {"num_noc_spikes", "num_local_spikes", "total_hops",
+                  "link_traversals", "dynamic_energy_pj", "per_link_hops"}
+
+
+@pytest.mark.parametrize("cast,hot,link_capacity", [
+    ("unicast", False, 256), ("unicast", True, 4),
+    ("multicast", False, 256), ("multicast", True, 4),
+])
+def test_batched_matches_ref_on_8x8_bursty_trace(cast, hot, link_capacity):
+    """The same parity on a 64-core mesh, with routes of up to 14 hops and
+    hot-destination windows: unicast equal in every field, multicast
+    (tree fork against replicas) in the static ones."""
+    if cast == "unicast":
+        trace = bursty_trace(60_000, hot=hot)
+    else:
+        trace = bursty_trace(8_000, fan=6, hot=hot, hot_frac=0.5, nhot=4)
+    args = dict(link_capacity=link_capacity, cast=cast)
+    ref = simulate_noc(*trace, 8, 8, engine="ref", **args)
+    new = simulate_noc(*trace, 8, 8, engine="batched", **args)
+    assert (ref.congestion_count > 0) == hot  # the hot windows do queue
+    mism = stats_equal(ref, new)
+    if cast == "multicast":
+        mism = [k for k in mism if k in _STATIC_FIELDS]
+    assert mism == [], (cast, link_capacity)
+
+
 def hot_link_trace(seed):
     """Random traffic with a burst over one link: 400 spikes from core 0 to
     its east neighbour in two time steps, so many packets share an inject

@@ -247,6 +247,20 @@ def test_end_to_end_sharded_quality_within_5pct():
     assert drift <= 0.05, f"sharded comm_volume drifted {drift:.1%}"
 
 
+def test_sharded_cut_partition_at_scale_within_5pct():
+    """The same bound under the cut objective on a larger fan-out SNN with
+    1024-neuron cores: 4 shards and 2 shards give the identical partition,
+    and it drifts at most 5% in comm_volume from single host."""
+    g = fanout_snn_graph(10_000, fan=10, seed=0, max_fire=30)
+    kw = dict(capacity=1024, seed=0, impl="vec", objective="cut")
+    single = sneap_partition(g, **kw)
+    four = sneap_partition(g, shards=4, **kw)
+    two = sneap_partition(g, shards=2, **kw)
+    assert np.array_equal(four.part, two.part)  # shard-count invariance
+    drift = abs(four.comm_volume - single.comm_volume) / single.comm_volume
+    assert drift <= 0.05, f"sharded comm_volume drifted {drift:.1%}"
+
+
 # ----------------------------------------------------- index-dtype audit
 
 
